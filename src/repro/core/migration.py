@@ -8,8 +8,9 @@ buffer.
 
 TPU realization:
   * aggregation: kernels/kv_gather (Pallas pipelined block DMA);
-  * transfer: one resharding program over ICI (`jax.device_put` to the new
-    mesh's NamedSharding — lowered to collective-permute / all-to-all);
+  * transfer: one resharding program over ICI (a jitted identity whose
+    out_shardings are the new mesh's — lowered to collective-permute /
+    all-to-all);
   * the analytic latency model below reproduces the paper's Fig. 7
     (naive per-page vs aggregated vs pipelined) for the simulator and
     benchmark; on-chip numbers come from the dry-run roofline constants.
@@ -49,12 +50,37 @@ class MigrationAborted(RuntimeError):
     can retry on a reduced pool or restart the sequences from scratch."""
 
 
+def reshard(tree, target_shardings):
+    """Move `tree` (all leaves on one mesh) to `target_shardings` (all on
+    one mesh over the same devices) so that every byte stays on the devices
+    and crosses ICI.
+
+    `jax.device_put` does so itself when the two meshes list their devices
+    in different orders (TP 1 <-> TP 2 under `make_exec_mesh`): it reorders
+    the shards and reshards with a compiled identity. For meshes in the same
+    order (TP 1 <-> TP 4) it would copy every array through the host, so
+    that case runs the compiled identity directly.
+    """
+    src = jax.tree_util.tree_leaves(tree)[0].sharding
+    dst = jax.tree_util.tree_leaves(target_shardings)[0]
+    if (isinstance(src, NamedSharding) and isinstance(dst, NamedSharding)
+            and list(src.mesh.devices.flat) == list(dst.mesh.devices.flat)):
+        # a module-level function, so that JAX's caches find the compiled
+        # program again on the next switch between the same layouts
+        return jax.jit(_identity, out_shardings=target_shardings)(tree)
+    return jax.tree_util.tree_map(jax.device_put, tree, target_shardings)
+
+
+def _identity(tree):
+    return tree
+
+
 def migrate_cache(cache, target_shardings):
     """Stop-and-migrate: reshard every cache leaf to the new TP layout.
 
-    Under jit/device_put this lowers to ICI collectives on TPU. Returns the
-    migrated cache and the host-measured wall time (meaningful on the real
-    mini-cluster; the simulator uses `migration_time_model`).
+    Lowers to ICI collectives on TPU. Returns the migrated cache and the
+    host-measured wall time (meaningful on the real mini-cluster; the
+    simulator uses `migration_time_model`).
 
     Abort-safe: a mid-flight failure (source or target device dying, OOM
     on the target layout) raises ``MigrationAborted`` with the original
@@ -62,7 +88,7 @@ def migrate_cache(cache, target_shardings):
     """
     t0 = time.perf_counter()
     try:
-        out = jax.tree_util.tree_map(jax.device_put, cache, target_shardings)
+        out = reshard(cache, target_shardings)
         jax.block_until_ready(out)
     except MigrationAborted:
         raise

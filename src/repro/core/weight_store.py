@@ -25,14 +25,13 @@ from dataclasses import dataclass
 from typing import List, Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.params import ParamDef, is_def, tree_map_defs
 from repro.parallel.sharding import (
-    ShardingRules, make_exec_config, pspec_for, shard_map_compat,
+    ShardingRules, make_exec_config, pspec_for,
 )
 
 
@@ -116,29 +115,35 @@ class WeightStore:
         ]
         return jax.tree_util.tree_unflatten(self.treedef, specs)
 
-    def build(self, canonical_params, mesh: Optional[Mesh] = None):
-        """Tile canonical params into the storage layout (done once at load).
+    def build(self, canonical_params, mesh: Mesh):
+        """Place canonical params on `mesh` in the storage layout (done once
+        at load).
 
-        Real deployments construct shards locally; here we build the global
-        tiled array and (optionally) place it on `mesh`.
+        Each device receives only its own storage shard, so no device ever
+        holds the tiled global array. A shard that is a whole canonical leaf
+        already on its device (storage_tp=1) reuses that buffer: the caller
+        may drop its canonical params afterwards without freeing anything.
         """
         flat = jax.tree_util.tree_leaves(canonical_params)
         out = []
-        for x, plan, idx in zip(flat, self.plans, range(len(flat))):
+        for idx, (x, plan) in enumerate(zip(flat, self.plans)):
+            sh = NamedSharding(mesh, self.storage_pspec(idx))
             if plan.dim is None:
-                t = x
-            else:
-                n = plan.n_units
-                w = n // self.s  # units per storage shard
-                reps = self.N // self.s
+                out.append(jax.device_put(x, sh))
+                continue
+            w = plan.n_units // self.s  # units per storage shard
+            shape = list(x.shape)
+            shape[plan.dim] = w * self.N
+            bufs = []
+            for d, index in sh.devices_indices_map(tuple(shape)).items():
                 # pool position j holds canonical shard floor(j*s/N)
-                idxs = np.concatenate([
-                    np.arange(w) + (j * self.s // self.N) * w for j in range(self.N)
-                ])
-                t = jnp.take(x, jnp.asarray(idxs), axis=plan.dim)
-            if mesh is not None:
-                t = jax.device_put(t, NamedSharding(mesh, self.storage_pspec(idx)))
-            out.append(t)
+                j = (index[plan.dim].start or 0) // w
+                c = j * self.s // self.N
+                src = x if w == plan.n_units else jax.lax.slice_in_dim(
+                    x, c * w, (c + 1) * w, axis=plan.dim
+                )
+                bufs.append(jax.device_put(src, d))
+            out.append(jax.make_array_from_single_device_arrays(tuple(shape), sh, bufs))
         return jax.tree_util.tree_unflatten(self.treedef, out)
 
     # ---- pool shrink after device / host loss ---------------------------
@@ -173,9 +178,9 @@ class WeightStore:
         out = []
         for i, x in enumerate(flat):
             sh = NamedSharding(new_mesh, self.storage_pspec(i))
-            if x.sharding.is_equivalent_to(sh, x.ndim):
-                out.append(x)
-                continue
+            # relabel even an equivalent sharding: each TP's executables were
+            # compiled against arrays bound to that TP's mesh, and a stale
+            # mesh label would make them compile again.
             # device order is identical by construction; reuse buffers
             dev_to_buf = {s.device: s.data for s in x.addressable_shards}
             bufs = []
@@ -220,7 +225,7 @@ class WeightStore:
                 outs.append(jax.lax.dynamic_slice_in_dim(x, off, width, plan.dim))
             return tuple(outs)
 
-        smapped = shard_map_compat(
+        smapped = jax.shard_map(
             inner, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )

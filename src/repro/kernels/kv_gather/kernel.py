@@ -22,6 +22,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _rows(F: int):
+    """Tile view of one F-element page: (F // 128, 128) when F fills whole
+    lanes, else (1, F). Either way the block's last two dims equal the
+    array's, which is what the TPU compiler accepts for any F."""
+    lanes = 128 if F % 128 == 0 else F
+    return F // lanes, lanes
+
+
 def _copy_kernel(ids_ref, src_ref, dst_ref):
     dst_ref[...] = src_ref[...]
 
@@ -29,18 +37,20 @@ def _copy_kernel(ids_ref, src_ref, dst_ref):
 def kv_gather_p(pool, page_ids, *, interpret: bool):
     """pool: (P, F); page_ids: (n,) int32 -> staged (n, F)."""
     n = page_ids.shape[0]
-    F = pool.shape[1]
-    return pl.pallas_call(
+    P, F = pool.shape
+    r, lanes = _rows(F)
+    staged = pl.pallas_call(
         _copy_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n,),
-            in_specs=[pl.BlockSpec((1, F), lambda i, ids: (ids[i], 0))],
-            out_specs=pl.BlockSpec((1, F), lambda i, ids: (i, 0)),
+            in_specs=[pl.BlockSpec((1, r, lanes), lambda i, ids: (ids[i], 0, 0))],
+            out_specs=pl.BlockSpec((1, r, lanes), lambda i, ids: (i, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((n, F), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, r, lanes), pool.dtype),
         interpret=interpret,
-    )(jnp.asarray(page_ids, jnp.int32), pool)
+    )(jnp.asarray(page_ids, jnp.int32), pool.reshape(P, r, lanes))
+    return staged.reshape(n, F)
 
 
 def _scatter_kernel(ids_ref, pool_ref, staged_ref, out_ref):
@@ -54,20 +64,23 @@ def kv_scatter_p(pool, staged, page_ids, *, interpret: bool):
     The pool is donated/aliased: untouched pages keep their contents.
     """
     n = page_ids.shape[0]
-    F = pool.shape[1]
-    dst = pl.BlockSpec((1, F), lambda i, ids: (ids[i], 0))
-    return pl.pallas_call(
+    P, F = pool.shape
+    r, lanes = _rows(F)
+    dst = pl.BlockSpec((1, r, lanes), lambda i, ids: (ids[i], 0, 0))
+    out = pl.pallas_call(
         _scatter_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n,),
             in_specs=[
                 dst,  # pool (aliased with the output)
-                pl.BlockSpec((1, F), lambda i, ids: (i, 0)),  # staged
+                pl.BlockSpec((1, r, lanes), lambda i, ids: (i, 0, 0)),  # staged
             ],
             out_specs=dst,
         ),
-        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((P, r, lanes), pool.dtype),
         input_output_aliases={1: 0},  # pool -> out (index counts the scalar)
         interpret=interpret,
-    )(jnp.asarray(page_ids, jnp.int32), pool, staged)
+    )(jnp.asarray(page_ids, jnp.int32), pool.reshape(P, r, lanes),
+      staged.reshape(n, r, lanes))
+    return out.reshape(P, F)

@@ -9,8 +9,9 @@ import jax.numpy as jnp
 from repro.kernels.kv_gather.kernel import kv_gather_p, kv_scatter_p
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Pallas runs interpreted only on the CPU backend."""
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -29,9 +30,9 @@ def kv_gather(pool, page_ids):
     pool: (num_pages, F) — flattened page payloads; page_ids: (n,) int32.
     Returns staged (n, F).
     """
-    return _gather(pool, jnp.asarray(page_ids, jnp.int32), not _on_tpu())
+    return _gather(pool, jnp.asarray(page_ids, jnp.int32), _interpret())
 
 
 def kv_scatter(pool, staged, page_ids):
     """Write a contiguous staging buffer back into (donated) pool pages."""
-    return _scatter(pool, staged, jnp.asarray(page_ids, jnp.int32), not _on_tpu())
+    return _scatter(pool, staged, jnp.asarray(page_ids, jnp.int32), _interpret())

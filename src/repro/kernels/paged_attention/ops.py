@@ -9,8 +9,9 @@ import jax.numpy as jnp
 from repro.kernels.paged_attention.kernel import paged_decode_attention_p
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Pallas runs interpreted only on the CPU backend."""
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
@@ -30,5 +31,5 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *, softc
     return _call(
         q, k_pages, v_pages,
         jnp.asarray(block_tables, jnp.int32), jnp.asarray(seq_lens, jnp.int32),
-        softcap, not _on_tpu(),
+        softcap, _interpret(),
     )

@@ -28,7 +28,10 @@ def attn_param_defs(cfg: ModelConfig, ec: ExecConfig) -> dict:
         "wq": ParamDef((d, ec.heads_exec, hd), ("embed", "heads", "head_dim")),
         "wk": ParamDef((d, ec.kv_exec, hd), ("embed", "kv_heads", "head_dim")),
         "wv": ParamDef((d, ec.kv_exec, hd), ("embed", "kv_heads", "head_dim")),
-        "wo": ParamDef((ec.heads_exec, hd, d), ("heads", "head_dim", "embed")),
+        "wo": ParamDef(
+            (ec.heads_exec, hd, d), ("heads", "head_dim", "embed"),
+            scale=(cfg.num_heads * hd) ** -0.5,  # fan-in is heads x head_dim
+        ),
     }
     if cfg.attn.qk_norm:
         defs["q_norm"] = ParamDef((hd,), ("head_dim",), init="zeros")

@@ -7,7 +7,6 @@ decode; caches thread through the scan as xs/ys.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
@@ -27,19 +26,6 @@ from repro.models.mamba import (
 from repro.models.moe import moe_apply, moe_param_defs
 from repro.models.params import ParamDef, stack_defs
 from repro.parallel.sharding import ExecConfig, shard_constraint
-
-
-@functools.lru_cache(maxsize=1)
-def _barrier_supports_ad() -> bool:
-    """optimization_barrier only gained a differentiation rule in newer jax;
-    on older versions the barrier (a pure scheduling hint) must be skipped
-    under grad rather than crash the train step. Probed lazily at the first
-    train-mode forward, not at import."""
-    try:
-        jax.grad(lambda x: jax.lax.optimization_barrier(x))(1.0)
-        return True
-    except Exception:  # noqa: BLE001 - any failure means "don't use it"
-        return False
 
 
 def _layer_window(cfg: ModelConfig, mixer: str) -> Optional[int]:
@@ -175,7 +161,7 @@ def forward(
             h, aux, nc = apply_layer(h, aux, lp, lc, t)
             if nc is not None:
                 new_pcache[f"pos{i}"] = nc
-            if mode == "train" and len(pattern) > 1 and _barrier_supports_ad():
+            if mode == "train" and len(pattern) > 1:
                 # barrier between in-period layers: stops the scheduler from
                 # hoisting every layer's remat-recompute ahead of the layer
                 # backwards (which would keep all layers' intermediates live)

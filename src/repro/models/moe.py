@@ -26,7 +26,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig, MoESpec
 from repro.models.params import ParamDef
-from repro.parallel.sharding import pspec_for, shard_constraint, shard_map_compat as shard_map
+from repro.parallel.sharding import pspec_for, shard_constraint
 
 
 def _expert_weight_specs(rules, mesh):
@@ -197,7 +197,7 @@ def moe_apply_sharded(p, x, cfg: ModelConfig, rules, mesh):
         return y.reshape(Bl, Sl, D), aux
 
     xspec = P(batch_axes if len(batch_axes) > 1 else (batch_axes[0] if batch_axes else None), "model", None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(xspec, P(None, None), wg_spec, wg_spec, wo_spec),
@@ -251,7 +251,7 @@ def _moe_apply_decode(p, x, cfg: ModelConfig, rules, mesh):
 
     bspec = batch_axes if len(batch_axes) > 1 else (batch_axes[0] if batch_axes else None)
     xspec = P(bspec if B % dp == 0 and dp > 1 else None, None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(xspec, P(None, None), wg_spec, wg_spec, wo_spec),

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.sharding import shard_map_compat
 
 
 def pipeline_apply(
@@ -81,7 +80,7 @@ def pipeline_apply(
         return jax.lax.psum(out, "pipe")
 
     pspec = P("pipe")
-    out = shard_map_compat(
+    out = jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(

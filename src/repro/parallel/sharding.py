@@ -17,20 +17,6 @@ from typing import Mapping, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at top level (check_vma kwarg)
-    from jax import shard_map as _shard_map_raw
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:  # older jax: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=False):
-    """Version-portable jax shard_map (the check kwarg was renamed
-    check_rep -> check_vma across jax releases)."""
-    kw = {_SHARD_MAP_CHECK_KW: check_vma}
-    return _shard_map_raw(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
 from repro.configs.base import ModelConfig, ceil_to
 
 MeshAxes = Union[None, str, Tuple[str, ...]]

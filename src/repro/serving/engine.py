@@ -20,14 +20,12 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.core.migration import cache_shardings, migrate_cache
+from repro.core.migration import cache_shardings, migrate_cache, reshard
 from repro.core.weight_store import WeightStore, make_exec_mesh
-from repro.models import forward, model_param_defs
+from repro.models import forward, init_cache_defs, model_param_defs
 from repro.models.model import logits_for
-from repro.models.params import init_params
 from repro.parallel.sharding import DEFAULT_RULES, make_exec_config
 from repro.serving.kv_cache import SlotCache
 from repro.serving.request import Request, RequestState
@@ -37,19 +35,58 @@ from repro.serving.request import Request, RequestState
 class EngineConfig:
     candidate_tps: Sequence[int] = (1, 2, 4, 8)
     n_slots: int = 16
-    max_len: int = 256
-    prefill_buckets: Sequence[int] = (32, 64, 128)
-    dtype: object = jnp.float32
+    max_len: int = 2048
+    prefill_buckets: Sequence[int] = (128, 512)
+    dtype: object = jnp.bfloat16
     record_logits: bool = False
 
 
 @dataclass
 class StepStats:
     steps: int = 0
-    switches: int = 0
-    rebind_s: float = 0.0
-    migrate_s: float = 0.0
     compile_s: float = 0.0
+    # one entry per executed switch: step, from_tp, to_tp, rebind_s, migrate_s
+    switch_log: List[dict] = field(default_factory=list)
+
+
+def make_decode_step(cfg: ModelConfig, store: WeightStore, tp: int, mesh,
+                     cache_ec, rules=DEFAULT_RULES):
+    """Jitted decode step at TP `tp` on `mesh`:
+    (storage, caches, tokens (B,1), positions (B,)) -> (next, logits, caches).
+    The caches are donated; their layout is fixed by `cache_ec`."""
+    sel = store.select_fn(tp, mesh)
+
+    def step(storage, caches, tokens, positions):
+        params = sel(storage)
+        h, new_caches, _ = forward(
+            params, cfg, cache_ec, rules=rules, mesh=mesh, tokens=tokens,
+            positions=positions, cache=caches, mode="decode",
+        )
+        logits = logits_for(params, cfg, h, rules, mesh)[:, 0, : cfg.vocab_size]
+        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        return nxt, logits, new_caches
+
+    return jax.jit(step, donate_argnums=(1,))
+
+
+def make_prefill_step(cfg: ModelConfig, store: WeightStore, tp: int, mesh,
+                      cache_ec, rules=DEFAULT_RULES):
+    """Jitted one-request prefill at TP `tp` on `mesh`:
+    (storage, tokens (1,L), true_len) -> (next, logits, sequence cache)."""
+    sel = store.select_fn(tp, mesh)
+
+    def pre(storage, tokens, true_len):
+        params = sel(storage)
+        h, cache, _ = forward(
+            params, cfg, cache_ec, rules=rules, mesh=mesh, tokens=tokens,
+            mode="prefill", block_q=64, block_k=64,
+        )
+        h_last = jax.lax.dynamic_slice_in_dim(h, true_len - 1, 1, axis=1)
+        logits = logits_for(params, cfg, h_last, rules, mesh)[:, 0, : cfg.vocab_size]
+        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        return nxt, logits, cache
+
+    return jax.jit(pre)
 
 
 class ServingEngine:
@@ -57,19 +94,20 @@ class ServingEngine:
         self,
         cfg: ModelConfig,
         canonical_params,
-        devices=None,
+        devices: Sequence,
         econf: EngineConfig = EngineConfig(),
         rules=DEFAULT_RULES,
     ):
         self.cfg = cfg
         self.econf = econf
         self.rules = rules
-        self.devices = list(devices if devices is not None else jax.devices())
+        self.devices = list(devices)
         tps = [t for t in econf.candidate_tps if t <= len(self.devices)]
-        assert cfg.num_kv_heads >= max(tps), (
-            "engine keeps kv_exec constant across TP levels; use a config "
-            "with num_kv_heads >= max candidate TP"
-        )
+        if cfg.num_kv_heads < max(tps):
+            raise ValueError(
+                f"{cfg.name}: {cfg.num_kv_heads} KV heads < max TP {max(tps)}; "
+                "the engine keeps kv_exec constant across TP levels"
+            )
         assert cfg.moe is None or cfg.moe.num_experts >= max(tps)
         self.tps = tps
 
@@ -79,73 +117,32 @@ class ServingEngine:
         self.tp = tps[0]
         self.storage = self.store.build(canonical_params, self.meshes[self.tp])
 
+        cache_ec = make_exec_config(cfg, max(tps))  # layout fixed at max-TP kv_exec
+        cache_defs = init_cache_defs(cfg, cache_ec, econf.n_slots, econf.max_len)
         self.slots = SlotCache.create(
-            cfg, make_exec_config(cfg, max(tps)), econf.n_slots, econf.max_len,
-            econf.dtype,
+            cfg, cache_ec, econf.n_slots, econf.max_len, econf.dtype,
+            cache_shardings(cache_defs, rules, self.meshes[self.tp]),
         )
-        self._place_cache(self.tp)
         self.slot_req: List[Optional[Request]] = [None] * econf.n_slots
         self.next_tokens = np.zeros(econf.n_slots, np.int32)
         self.stats = StepStats()
         self.logit_trace: Dict[int, list] = {}
 
         t0 = time.perf_counter()
-        self._decode_fns = {tp: self._make_decode(tp) for tp in tps}
-        self._prefill_fns = {
-            (tp, L): self._make_prefill(tp, L)
+        self._decode_fns = {
+            tp: make_decode_step(cfg, self.store, tp, self.meshes[tp], cache_ec, rules)
             for tp in tps
-            for L in econf.prefill_buckets
+        }
+        self._prefill_fns = {  # one executable per bucket length, by shape
+            tp: make_prefill_step(cfg, self.store, tp, self.meshes[tp], cache_ec, rules)
+            for tp in tps
         }
         self._insert_fn = jax.jit(self._insert, donate_argnums=(0,))
         self.stats.compile_s = time.perf_counter() - t0
 
     # ------------------------------------------------------------------
-    def _cache_ec(self):
-        return make_exec_config(self.cfg, max(self.tps))
-
-    def _place_cache(self, tp: int) -> None:
-        defs = self.slots.cache_defs()
-        target = cache_shardings(defs, self.rules, self.meshes[tp])
-        self.slots.arrays = jax.tree_util.tree_map(
-            jax.device_put, self.slots.arrays, target
-        )
-
-    def _make_decode(self, tp: int):
-        mesh = self.meshes[tp]
-        sel = self.store.select_fn(tp, mesh)
-        ec = self._cache_ec()  # cache layout fixed at max-TP kv_exec
-        cfg, rules = self.cfg, self.rules
-
-        def step(storage, caches, tokens, positions):
-            params = sel(storage)
-            h, new_caches, _ = forward(
-                params, cfg, ec, rules=rules, mesh=mesh, tokens=tokens,
-                positions=positions, cache=caches, mode="decode",
-            )
-            logits = logits_for(params, cfg, h, rules, mesh)[:, 0, : cfg.vocab_size]
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            return nxt, logits, new_caches
-
-        return jax.jit(step, donate_argnums=(1,))
-
-    def _make_prefill(self, tp: int, L: int):
-        mesh = self.meshes[tp]
-        sel = self.store.select_fn(tp, mesh)
-        ec = self._cache_ec()
-        cfg, rules = self.cfg, self.rules
-
-        def pre(storage, tokens, true_len):
-            params = sel(storage)
-            h, cache, _ = forward(
-                params, cfg, ec, rules=rules, mesh=mesh, tokens=tokens,
-                mode="prefill", block_q=64, block_k=64,
-            )
-            h_last = jax.lax.dynamic_slice_in_dim(h, true_len - 1, 1, axis=1)
-            logits = logits_for(params, cfg, h_last, rules, mesh)[:, 0, : cfg.vocab_size]
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            return nxt, logits, cache
-
-        return jax.jit(pre)
+    def _cache_shardings(self, tp: int):
+        return cache_shardings(self.slots.cache_defs(), self.rules, self.meshes[tp])
 
     @staticmethod
     def _insert(caches, seq_cache, slot):
@@ -159,8 +156,9 @@ class ServingEngine:
 
     # ------------------------------------------------------------------
     def warmup(self) -> float:
-        """AOT-warm every (tp, stage) executable — the paper's offline
-        CUDA-graph capture. Returns total compile seconds."""
+        """AOT-warm every (tp, stage) executable, and the cache resharding
+        between consecutive candidate TPs — the paper's offline CUDA-graph
+        capture. Returns total compile seconds."""
         t0 = time.perf_counter()
         dummy_tok = np.zeros((self.econf.n_slots, 1), np.int32)
         dummy_pos = np.zeros((self.econf.n_slots,), np.int32)
@@ -172,10 +170,12 @@ class ServingEngine:
             )
             jax.block_until_ready(nxt)
             for L in self.econf.prefill_buckets:
-                t, _, _ = self._prefill_fns[(tp, L)](
+                _, _, seq_cache = self._prefill_fns[tp](
                     self.storage, np.zeros((1, L), np.int32), 1
                 )
-                jax.block_until_ready(t)
+                # slot 0 holds junk until its first admit overwrites it
+                self.slots.arrays = self._insert_fn(self.slots.arrays, seq_cache, 0)
+                jax.block_until_ready(self.slots.arrays)
         self._switch_mesh_only(cur)
         dt = time.perf_counter() - t0
         self.stats.compile_s += dt
@@ -185,24 +185,29 @@ class ServingEngine:
         if tp == self.tp:
             return
         self.storage = self.store.rebind(self.storage, self.meshes[tp])
-        self._place_cache(tp)
+        self.slots.arrays = reshard(self.slots.arrays, self._cache_shardings(tp))
         self.tp = tp
 
     def switch_tp(self, tp: int) -> dict:
         """Stop-and-migrate TP switch (paper §3.2): zero-copy weight rebind +
         one resharding program for all slot caches."""
+        if tp not in self.meshes:
+            raise ValueError(
+                f"switch_tp({tp}): no mesh for TP {tp}; this engine has TPs {self.tps}"
+            )
         if tp == self.tp:
             return {"rebind_s": 0.0, "migrate_s": 0.0}
         t0 = time.perf_counter()
         self.storage = self.store.rebind(self.storage, self.meshes[tp])
         rebind_s = time.perf_counter() - t0
-        defs = self.slots.cache_defs()
-        target = cache_shardings(defs, self.rules, self.meshes[tp])
-        self.slots.arrays, migrate_s = migrate_cache(self.slots.arrays, target)
+        self.slots.arrays, migrate_s = migrate_cache(
+            self.slots.arrays, self._cache_shardings(tp)
+        )
+        self.stats.switch_log.append({
+            "step": self.stats.steps, "from_tp": self.tp, "to_tp": tp,
+            "rebind_s": rebind_s, "migrate_s": migrate_s,
+        })
         self.tp = tp
-        self.stats.switches += 1
-        self.stats.rebind_s += rebind_s
-        self.stats.migrate_s += migrate_s
         return {"rebind_s": rebind_s, "migrate_s": migrate_s}
 
     # ------------------------------------------------------------------
@@ -221,11 +226,11 @@ class ServingEngine:
         L = self._bucket(req.prompt_len)
         tokens = np.zeros((1, L), np.int32)
         tokens[0, : req.prompt_len] = req.prompt
-        nxt, logits, seq_cache = self._prefill_fns[(self.tp, L)](
+        nxt, logits, seq_cache = self._prefill_fns[self.tp](
             self.storage, tokens, req.prompt_len
         )
         self.slots.arrays = self._insert_fn(self.slots.arrays, seq_cache, slot)
-        tok = int(nxt[0])
+        tok = int(np.asarray(nxt)[0])
         req.slot = slot
         req.state = RequestState.DECODE
         req.generated.append(tok)
@@ -234,7 +239,7 @@ class ServingEngine:
         self.slots.lengths[slot] = req.prompt_len
         self.next_tokens[slot] = tok
         if self.econf.record_logits:
-            self.logit_trace.setdefault(req.req_id, []).append(np.asarray(logits[0]))
+            self.logit_trace.setdefault(req.req_id, []).append(np.asarray(logits)[0])
         return True
 
     def step(self) -> List[Request]:

@@ -21,7 +21,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import init_cache_defs
-from repro.models.params import init_params
+from repro.models.params import is_def
 from repro.parallel.sharding import ExecConfig
 
 
@@ -39,9 +39,12 @@ class SlotCache:
     free: Deque[int] = None
 
     @classmethod
-    def create(cls, cfg, ec, n_slots, max_len, dtype=jnp.float32):
-        defs = init_cache_defs(cfg, ec, n_slots, max_len)
-        arrays = init_params(defs, jax.random.PRNGKey(0), dtype)
+    def create(cls, cfg, ec, n_slots, max_len, dtype, shardings):
+        """Zero caches created in place, one leaf per entry of `shardings`."""
+        arrays = jax.tree_util.tree_map(
+            lambda d, s: jnp.zeros(d.shape, dtype, device=s),
+            init_cache_defs(cfg, ec, n_slots, max_len), shardings, is_leaf=is_def,
+        )
         return cls(
             cfg, ec, n_slots, max_len, arrays,
             np.zeros(n_slots, np.int64), deque(range(n_slots)),

@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-pytest.importorskip("hypothesis")  # not in the base image; property tests skip
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.tp_shard_matmul.ops import tp_shard_matmul
@@ -28,8 +27,8 @@ def _tol(dtype):
     [
         (64, 128, 512, 128, 0),
         (64, 128, 512, 128, 3),
-        (128, 256, 256, 64, 2),
-        (32, 64, 576, 144, 1),  # non-128-aligned (gemma2 d_ff/16 = 576)
+        (128, 256, 1024, 256, 2),
+        (32, 64, 144, 144, 0),  # non-128-aligned: the whole width as one lane block
         (256, 512, 1024, 512, 1),
     ],
 )
@@ -82,9 +81,12 @@ def test_tp_shard_matmul_equals_presliced_weights():
     tp=st.sampled_from([1, 2, 4]), shard=st.integers(0, 3), seed=st.integers(0, 99),
 )
 def test_tp_shard_matmul_property(mb, kb, nb, tp, shard, seed):
-    m, k, n_full = 8 * mb, 8 * kb, 32 * nb
+    # tile-legal widths: a shard of 128 * nb columns, or at TP 1 the whole
+    # weight, which may be any width
+    m, k = 8 * mb, 8 * kb
+    n_out = 128 * nb if tp > 1 else 32 * nb
+    n_full = n_out * tp
     shard = shard % tp
-    n_out = n_full // tp
     kx, kw = jax.random.split(jax.random.PRNGKey(seed))
     x = jax.random.normal(kx, (m, k), jnp.float32)
     w = jax.random.normal(kw, (k, n_full), jnp.float32)

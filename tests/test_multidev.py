@@ -33,7 +33,8 @@ def test_moe_sharded_matches_local_oracle():
 
 
 def test_kv_migration_preserves_contents():
-    _run("migration")
+    out = _run("migration")
+    assert "without a host copy" in out
 
 
 def test_fault_aborts_are_transactional():

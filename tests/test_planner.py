@@ -1,7 +1,6 @@
 """Planner + perf-model unit & property tests."""
 import numpy as np
 import pytest
-pytest.importorskip("hypothesis")  # not in the base image; property tests skip
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config

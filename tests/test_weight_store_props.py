@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-pytest.importorskip("hypothesis")  # not in the base image; property tests skip
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_config
