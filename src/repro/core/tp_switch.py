@@ -9,8 +9,7 @@ migrated by core/migration.
 """
 from __future__ import annotations
 
-import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -29,10 +28,6 @@ class SwitchAborted(RuntimeError):
 class SwitchStats:
     n_switches: int = 0
     n_aborts: int = 0
-    total_rebind_s: float = 0.0
-    total_migrate_s: float = 0.0
-    last_rebind_s: float = 0.0
-    last_migrate_s: float = 0.0
 
 
 class ExecutableCache:
@@ -42,12 +37,9 @@ class ExecutableCache:
 
     def __init__(self):
         self._exe: Dict[Tuple[int, Any], Any] = {}
-        self.compile_s: Dict[Tuple[int, Any], float] = {}
 
     def put(self, tp: int, key: Any, lowered) -> None:
-        t0 = time.perf_counter()
         self._exe[(tp, key)] = lowered.compile()
-        self.compile_s[(tp, key)] = time.perf_counter() - t0
 
     def get(self, tp: int, key: Any):
         return self._exe[(tp, key)]
@@ -90,14 +82,11 @@ class TPSwitchController:
         """
         assert self.storage is not None
         prev_storage, prev_tp = self.storage, self.current_tp
-        t0 = time.perf_counter()
         self.storage = self.store.rebind(self.storage, self.meshes[to_tp])
-        rebind_s = time.perf_counter() - t0
-        migrate_s = 0.0
         migrated = None
         if migrate_fn is not None:
             try:
-                migrated, migrate_s = migrate_fn(self.meshes[to_tp])
+                migrated, _ = migrate_fn(self.meshes[to_tp])
             except Exception as e:
                 self.storage, self.current_tp = prev_storage, prev_tp
                 self.stats.n_aborts += 1
@@ -106,11 +95,7 @@ class TPSwitchController:
                     f"migration: {e}"
                 ) from e
         self.current_tp = to_tp
-        st = self.stats
-        st.n_switches += 1
-        st.total_rebind_s += rebind_s
-        st.total_migrate_s += migrate_s
-        st.last_rebind_s, st.last_migrate_s = rebind_s, migrate_s
+        self.stats.n_switches += 1
         return migrated
 
 

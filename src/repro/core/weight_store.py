@@ -232,7 +232,8 @@ class WeightStore:
 
         def select(storage):
             flat = jax.tree_util.tree_leaves(storage)
-            outs = smapped(*flat)
+            with jax.named_scope("select"):
+                outs = smapped(*flat)
             return jax.tree_util.tree_unflatten(self.treedef, list(outs))
 
         return select

@@ -188,17 +188,18 @@ def attn_apply(
         ).astype(x.dtype)
         new_cache = None
         if mode == "prefill":
-            if window is not None and S > window:
-                slots = swa_cache_slots(window, S)
-                ck = jnp.zeros((B, window, KV, hd), k.dtype).at[:, slots].set(
-                    k[:, -window:]
-                )
-                cv = jnp.zeros((B, window, KV, hd), v.dtype).at[:, slots].set(
-                    v[:, -window:]
-                )
-                new_cache = {"k": ck, "v": cv}
-            else:
-                new_cache = {"k": k, "v": v}
+            with jax.named_scope("kv_write"):
+                if window is not None and S > window:
+                    slots = swa_cache_slots(window, S)
+                    ck = jnp.zeros((B, window, KV, hd), k.dtype).at[:, slots].set(
+                        k[:, -window:]
+                    )
+                    cv = jnp.zeros((B, window, KV, hd), v.dtype).at[:, slots].set(
+                        v[:, -window:]
+                    )
+                    new_cache = {"k": ck, "v": cv}
+                else:
+                    new_cache = {"k": k, "v": v}
         o = o.reshape(B, S, ec.heads_exec, hd)
     else:
         assert cache is not None
@@ -212,12 +213,13 @@ def attn_apply(
             valid = jnp.arange(Sc)[None] <= positions[:, None]
         k1 = k[:, 0]  # (B,KV,hd)
         v1 = v[:, 0]
-        ck = jax.vmap(lambda c, s, val: jax.lax.dynamic_update_slice(c, val[None], (s, 0, 0)))(
-            cache["k"], slot, k1
-        )
-        cv = jax.vmap(lambda c, s, val: jax.lax.dynamic_update_slice(c, val[None], (s, 0, 0)))(
-            cache["v"], slot, v1
-        )
+        with jax.named_scope("kv_write"):
+            ck = jax.vmap(lambda c, s, val: jax.lax.dynamic_update_slice(c, val[None], (s, 0, 0)))(
+                cache["k"], slot, k1
+            )
+            cv = jax.vmap(lambda c, s, val: jax.lax.dynamic_update_slice(c, val[None], (s, 0, 0)))(
+                cache["v"], slot, v1
+            )
         new_cache = {"k": ck, "v": cv}
         qg = q[:, 0].reshape(B, KV, G, hd)
         o = decode_attention(qg, ck, cv, valid, cap, rules, mesh)

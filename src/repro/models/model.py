@@ -100,9 +100,10 @@ def forward(
     """Returns (hidden (B,S,D) post-final-norm, new_cache, aux)."""
     assert mode in ("train", "prefill", "decode")
     if embeds is None:
-        h = jnp.take(params["embed"], tokens, axis=0)
-        if cfg.tie_embeddings:  # gemma convention: scale tied embeddings
-            h = h * jnp.asarray(cfg.d_model**0.5, h.dtype)
+        with jax.named_scope("embed"):
+            h = jnp.take(params["embed"], tokens, axis=0)
+            if cfg.tie_embeddings:  # gemma convention: scale tied embeddings
+                h = h * jnp.asarray(cfg.d_model**0.5, h.dtype)
     else:
         h = embeds
     B, S = h.shape[0], h.shape[1]
@@ -112,36 +113,39 @@ def forward(
     pattern = cfg.layer_pattern
 
     def apply_layer(h, aux, lp, lc, t):
-        resid = h
-        hn = rmsnorm(h, lp["norm1"], cfg.norm_eps)
-        if t.mixer.startswith("attn"):
-            y, nc = attn_apply(
-                lp["mixer"],
-                hn,
-                cfg=cfg,
-                ec=ec,
-                rules=rules,
-                mesh=mesh,
-                positions=positions,
-                window=_layer_window(cfg, t.mixer),
-                mode=mode,
-                cache=lc,
-                block_q=block_q,
-                block_k=block_k,
-            )
-        else:
-            fn = mamba2_apply if cfg.mamba.version == 2 else mamba1_apply
-            y, nc = fn(lp["mixer"], hn, cfg=cfg, rules=rules, mesh=mesh, mode=mode, cache=lc)
-        h = resid + y
-        if t.ffn != "none":
+        # named scopes ("attention", "mlp") cover each block's norm and residual
+        with jax.named_scope("attention" if t.mixer.startswith("attn") else t.mixer):
             resid = h
-            hn = rmsnorm(h, lp["norm2"], cfg.norm_eps)
-            if t.ffn == "dense":
-                y = mlp_apply(lp["ffn"], hn, rules, mesh)
+            hn = rmsnorm(h, lp["norm1"], cfg.norm_eps)
+            if t.mixer.startswith("attn"):
+                y, nc = attn_apply(
+                    lp["mixer"],
+                    hn,
+                    cfg=cfg,
+                    ec=ec,
+                    rules=rules,
+                    mesh=mesh,
+                    positions=positions,
+                    window=_layer_window(cfg, t.mixer),
+                    mode=mode,
+                    cache=lc,
+                    block_q=block_q,
+                    block_k=block_k,
+                )
             else:
-                y, a = moe_apply(lp["ffn"], hn, cfg, rules, mesh)
-                aux = {k: aux[k] + a[k] for k in aux}
+                fn = mamba2_apply if cfg.mamba.version == 2 else mamba1_apply
+                y, nc = fn(lp["mixer"], hn, cfg=cfg, rules=rules, mesh=mesh, mode=mode, cache=lc)
             h = resid + y
+        if t.ffn != "none":
+            with jax.named_scope("mlp" if t.ffn == "dense" else t.ffn):
+                resid = h
+                hn = rmsnorm(h, lp["norm2"], cfg.norm_eps)
+                if t.ffn == "dense":
+                    y = mlp_apply(lp["ffn"], hn, rules, mesh)
+                else:
+                    y, a = moe_apply(lp["ffn"], hn, cfg, rules, mesh)
+                    aux = {k: aux[k] + a[k] for k in aux}
+                h = resid + y
         return h, aux, nc
 
     # two-level remat for multi-layer periods (jamba's 8-layer block):

@@ -30,6 +30,22 @@ from repro.parallel.sharding import DEFAULT_RULES, make_exec_config
 from repro.serving.kv_cache import SlotCache
 from repro.serving.request import Request, RequestState
 
+# Host spans the engine opens (jax.profiler.TraceAnnotation, and
+# StepTraceAnnotation for "engine.step"): they land in the profiler's trace on
+# the device planes' clock. Identifiers (req_id, step_num, from_tp, to_tp) go
+# in as trace stats, so the names stay fixed.
+SPANS = (
+    "engine.admit", "engine.prefill", "engine.insert", "engine.first_token",
+    "engine.step", "engine.decode", "engine.sync", "engine.logits", "engine.slots",
+    "engine.switch", "engine.rebind", "engine.migrate",
+)
+# jax.named_scope names inside the compiled programs, read back from each
+# device op's op_name: "select" (WeightStore.select_fn), "embed", "attention"
+# with "kv_write" around cache updates, "mlp" (models/), "head" (the logits
+# and argmax, here) and "migrate" (core/migration.migrate).
+SCOPES = ("select", "embed", "attention", "kv_write", "mlp", "head", "migrate")
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclass
 class EngineConfig:
@@ -44,7 +60,6 @@ class EngineConfig:
 @dataclass
 class StepStats:
     steps: int = 0
-    compile_s: float = 0.0
     # one entry per executed switch: step, from_tp, to_tp, rebind_s, migrate_s
     switch_log: List[dict] = field(default_factory=list)
 
@@ -56,17 +71,18 @@ def make_decode_step(cfg: ModelConfig, store: WeightStore, tp: int, mesh,
     The caches are donated; their layout is fixed by `cache_ec`."""
     sel = store.select_fn(tp, mesh)
 
-    def step(storage, caches, tokens, positions):
+    def decode_step(storage, caches, tokens, positions):
         params = sel(storage)
         h, new_caches, _ = forward(
             params, cfg, cache_ec, rules=rules, mesh=mesh, tokens=tokens,
             positions=positions, cache=caches, mode="decode",
         )
-        logits = logits_for(params, cfg, h, rules, mesh)[:, 0, : cfg.vocab_size]
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            logits = logits_for(params, cfg, h, rules, mesh)[:, 0, : cfg.vocab_size]
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
         return nxt, logits, new_caches
 
-    return jax.jit(step, donate_argnums=(1,))
+    return jax.jit(decode_step, donate_argnums=(1,))
 
 
 def make_prefill_step(cfg: ModelConfig, store: WeightStore, tp: int, mesh,
@@ -75,18 +91,19 @@ def make_prefill_step(cfg: ModelConfig, store: WeightStore, tp: int, mesh,
     (storage, tokens (1,L), true_len) -> (next, logits, sequence cache)."""
     sel = store.select_fn(tp, mesh)
 
-    def pre(storage, tokens, true_len):
+    def prefill_step(storage, tokens, true_len):
         params = sel(storage)
         h, cache, _ = forward(
             params, cfg, cache_ec, rules=rules, mesh=mesh, tokens=tokens,
             mode="prefill", block_q=64, block_k=64,
         )
-        h_last = jax.lax.dynamic_slice_in_dim(h, true_len - 1, 1, axis=1)
-        logits = logits_for(params, cfg, h_last, rules, mesh)[:, 0, : cfg.vocab_size]
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            h_last = jax.lax.dynamic_slice_in_dim(h, true_len - 1, 1, axis=1)
+            logits = logits_for(params, cfg, h_last, rules, mesh)[:, 0, : cfg.vocab_size]
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
         return nxt, logits, cache
 
-    return jax.jit(pre)
+    return jax.jit(prefill_step)
 
 
 class ServingEngine:
@@ -128,7 +145,6 @@ class ServingEngine:
         self.stats = StepStats()
         self.logit_trace: Dict[int, list] = {}
 
-        t0 = time.perf_counter()
         self._decode_fns = {
             tp: make_decode_step(cfg, self.store, tp, self.meshes[tp], cache_ec, rules)
             for tp in tps
@@ -137,22 +153,23 @@ class ServingEngine:
             tp: make_prefill_step(cfg, self.store, tp, self.meshes[tp], cache_ec, rules)
             for tp in tps
         }
-        self._insert_fn = jax.jit(self._insert, donate_argnums=(0,))
-        self.stats.compile_s = time.perf_counter() - t0
+        self._insert_fn = jax.jit(self.insert_cache, donate_argnums=(0,))
 
     # ------------------------------------------------------------------
     def _cache_shardings(self, tp: int):
         return cache_shardings(self.slots.cache_defs(), self.rules, self.meshes[tp])
 
     @staticmethod
-    def _insert(caches, seq_cache, slot):
+    def insert_cache(caches, seq_cache, slot):
+        """Write one request's prefill cache into slot `slot` of every layer."""
         def upd(c, s):
             idx = (jnp.zeros((), jnp.int32), slot) + tuple(
                 jnp.zeros((), jnp.int32) for _ in range(c.ndim - 2)
             )
             return jax.lax.dynamic_update_slice(c, s.astype(c.dtype), idx)
 
-        return jax.tree_util.tree_map(upd, caches, seq_cache)
+        with jax.named_scope("kv_write"):
+            return jax.tree_util.tree_map(upd, caches, seq_cache)
 
     # ------------------------------------------------------------------
     def warmup(self) -> float:
@@ -177,9 +194,7 @@ class ServingEngine:
                 self.slots.arrays = self._insert_fn(self.slots.arrays, seq_cache, 0)
                 jax.block_until_ready(self.slots.arrays)
         self._switch_mesh_only(cur)
-        dt = time.perf_counter() - t0
-        self.stats.compile_s += dt
-        return dt
+        return time.perf_counter() - t0
 
     def _switch_mesh_only(self, tp: int) -> None:
         if tp == self.tp:
@@ -197,12 +212,15 @@ class ServingEngine:
             )
         if tp == self.tp:
             return {"rebind_s": 0.0, "migrate_s": 0.0}
-        t0 = time.perf_counter()
-        self.storage = self.store.rebind(self.storage, self.meshes[tp])
-        rebind_s = time.perf_counter() - t0
-        self.slots.arrays, migrate_s = migrate_cache(
-            self.slots.arrays, self._cache_shardings(tp)
-        )
+        with _span("engine.switch", from_tp=self.tp, to_tp=tp):
+            t0 = time.perf_counter()
+            with _span("engine.rebind"):
+                self.storage = self.store.rebind(self.storage, self.meshes[tp])
+            rebind_s = time.perf_counter() - t0
+            with _span("engine.migrate"):
+                self.slots.arrays, migrate_s = migrate_cache(
+                    self.slots.arrays, self._cache_shardings(tp)
+                )
         self.stats.switch_log.append({
             "step": self.stats.steps, "from_tp": self.tp, "to_tp": tp,
             "rebind_s": rebind_s, "migrate_s": migrate_s,
@@ -218,6 +236,10 @@ class ServingEngine:
         raise ValueError(f"prompt length {n} exceeds buckets")
 
     def admit(self, req: Request, now: float = 0.0) -> bool:
+        with _span("engine.admit", req_id=req.req_id):
+            return self._admit(req)
+
+    def _admit(self, req: Request) -> bool:
         slot = self.slots.alloc()
         if slot is None:
             return False
@@ -226,11 +248,14 @@ class ServingEngine:
         L = self._bucket(req.prompt_len)
         tokens = np.zeros((1, L), np.int32)
         tokens[0, : req.prompt_len] = req.prompt
-        nxt, logits, seq_cache = self._prefill_fns[self.tp](
-            self.storage, tokens, req.prompt_len
-        )
-        self.slots.arrays = self._insert_fn(self.slots.arrays, seq_cache, slot)
-        tok = int(np.asarray(nxt)[0])
+        with _span("engine.prefill"):
+            nxt, logits, seq_cache = self._prefill_fns[self.tp](
+                self.storage, tokens, req.prompt_len
+            )
+        with _span("engine.insert"):
+            self.slots.arrays = self._insert_fn(self.slots.arrays, seq_cache, slot)
+        with _span("engine.first_token"):
+            tok = int(np.asarray(nxt)[0])
         req.slot = slot
         req.state = RequestState.DECODE
         req.generated.append(tok)
@@ -244,30 +269,38 @@ class ServingEngine:
 
     def step(self) -> List[Request]:
         """One decode iteration over all active slots; returns finished."""
+        with jax.profiler.StepTraceAnnotation("engine.step", step_num=self.stats.steps):
+            return self._step()
+
+    def _step(self) -> List[Request]:
         tokens = self.next_tokens.reshape(-1, 1)
         positions = self.slots.lengths.astype(np.int32)
-        nxt, logits, self.slots.arrays = self._decode_fns[self.tp](
-            self.storage, self.slots.arrays, tokens, positions
-        )
-        nxt = np.asarray(nxt)
-        logits = np.asarray(logits)
+        with _span("engine.decode"):
+            nxt, logits, self.slots.arrays = self._decode_fns[self.tp](
+                self.storage, self.slots.arrays, tokens, positions
+            )
+        with _span("engine.sync"):
+            nxt = np.asarray(nxt)
+        with _span("engine.logits"):
+            logits = np.asarray(logits)
         self.stats.steps += 1
         finished = []
-        for slot, req in enumerate(self.slot_req):
-            if req is None:
-                continue
-            self.slots.lengths[slot] += 1
-            tok = int(nxt[slot])
-            req.generated.append(tok)
-            self.next_tokens[slot] = tok
-            if self.econf.record_logits:
-                self.logit_trace[req.req_id].append(logits[slot])
-            if req.done or self.slots.lengths[slot] + 1 >= self.econf.max_len:
-                req.state = RequestState.DONE
-                req.finish_s = time.perf_counter()
-                finished.append(req)
-                self.slot_req[slot] = None
-                self.slots.release(slot)
+        with _span("engine.slots"):
+            for slot, req in enumerate(self.slot_req):
+                if req is None:
+                    continue
+                self.slots.lengths[slot] += 1
+                tok = int(nxt[slot])
+                req.generated.append(tok)
+                self.next_tokens[slot] = tok
+                if self.econf.record_logits:
+                    self.logit_trace[req.req_id].append(logits[slot])
+                if req.done or self.slots.lengths[slot] + 1 >= self.econf.max_len:
+                    req.state = RequestState.DONE
+                    req.finish_s = time.perf_counter()
+                    finished.append(req)
+                    self.slot_req[slot] = None
+                    self.slots.release(slot)
         return finished
 
     def run(
