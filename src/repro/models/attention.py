@@ -13,13 +13,16 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import apply_rope, softcap
 from repro.models.params import ParamDef
-from repro.parallel.sharding import ExecConfig, shard_constraint
+from repro.parallel.sharding import ExecConfig, pspec_for, shard_constraint
 
 NEG_INF = -1e30
+LANES = 128  # a TPU vector register's lanes: the minor tile of every layout
+CACHE_AXES = ("batch", "kv_seq", "act_kv", "head_dim")  # one layer's K or V
 
 
 def attn_param_defs(cfg: ModelConfig, ec: ExecConfig) -> dict:
@@ -153,7 +156,8 @@ def attn_apply(
     positions,  # (S,) for train/prefill; (B,) for decode
     window: Optional[int],
     mode: str,  # train | prefill | decode
-    cache: Optional[dict] = None,  # {"k": (B,Sc,KV,hd), "v": ...} for decode
+    cache: Optional[dict] = None,  # decode: {"k": (L,B,Sc,KV,hd), "v": ...} over periods
+    layer=None,  # decode: this layer's index into the stacked cache
     block_q: int = 512,
     block_k: int = 512,
 ) -> Tuple[jnp.ndarray, Optional[dict]]:
@@ -202,8 +206,8 @@ def attn_apply(
                     new_cache = {"k": k, "v": v}
         o = o.reshape(B, S, ec.heads_exec, hd)
     else:
-        assert cache is not None
-        Sc = cache["k"].shape[1]
+        assert cache is not None and layer is not None
+        Sc = cache["k"].shape[2]
         if window is not None:
             slot = positions % window
             written_all = positions >= window
@@ -211,16 +215,14 @@ def attn_apply(
         else:
             slot = positions
             valid = jnp.arange(Sc)[None] <= positions[:, None]
-        k1 = k[:, 0]  # (B,KV,hd)
-        v1 = v[:, 0]
-        with jax.named_scope("kv_write"):
-            ck = jax.vmap(lambda c, s, val: jax.lax.dynamic_update_slice(c, val[None], (s, 0, 0)))(
-                cache["k"], slot, k1
-            )
-            cv = jax.vmap(lambda c, s, val: jax.lax.dynamic_update_slice(c, val[None], (s, 0, 0)))(
-                cache["v"], slot, v1
-            )
-        new_cache = {"k": ck, "v": cv}
+        with jax.named_scope("kv_write"):  # one row per slot, in place
+            new_cache = {
+                n: _write_rows(cache[n], r[:, 0].astype(cache[n].dtype), slot, layer, rules, mesh)
+                for n, r in (("k", k), ("v", v))
+            }
+        # read after the write: the slot's own row is part of its attention
+        ck, cv = (jax.lax.dynamic_index_in_dim(new_cache[n], layer, 0, keepdims=False)
+                  for n in ("k", "v"))
         qg = q[:, 0].reshape(B, KV, G, hd)
         o = decode_attention(qg, ck, cv, valid, cap, rules, mesh)
         o = o.astype(x.dtype).reshape(B, 1, ec.heads_exec, hd)
@@ -230,12 +232,42 @@ def attn_apply(
     return y, new_cache
 
 
+def _write_rows(cache, rows, slot, layer, rules, mesh):
+    """`cache` (L,B,Sc,KV,hd), stacked over periods, with each slot's row of
+    `rows` (B,KV,hd) written in place at [layer, b, slot[b]].
+
+    The scan over layers carries the cache in the layout its body asks for,
+    and copies the whole stack in and out where that is not the stored one.
+    A TPU stores a cache whose head_dim fills whole 128-lane tiles row-major,
+    and any other with the sequence on the lanes. A scatter asks for its rows
+    on the lanes, which keeps the first. Row-by-row updates ask for nothing,
+    so the carry takes the layout in which the attention reads its slice,
+    which for the second is the stored one (tests/test_chip_compile.py checks
+    both for a v5e). They run per device, so no slot's row crosses chips.
+    """
+    if rows.shape[-1] % LANES == 0:
+        return cache.at[layer, jnp.arange(rows.shape[0]), slot].set(rows)
+
+    def by_row(c, r, s, layer):
+        for b in range(r.shape[0]):
+            c = jax.lax.dynamic_update_slice(c, r[b][None, None, None], (layer, b, s[b], 0, 0))
+        return c
+
+    spec = pspec_for(("periods",) + CACHE_AXES, rules, mesh)
+    if mesh is None or spec[2] is not None:  # a sharded sequence: left to the partitioner
+        return by_row(cache, rows, slot, layer)
+    return jax.shard_map(
+        by_row, mesh=mesh,
+        in_specs=(spec, P(spec[1], spec[3], spec[4]), P(spec[1]), P()),
+        out_specs=spec, check_vma=False,
+    )(cache, rows, slot, layer)
+
+
 def attn_cache_defs(cfg: ModelConfig, ec: ExecConfig, batch: int, seq_len: int, window):
     """Cache ParamDefs for one attention layer (no leading period dim)."""
     Sc = min(window, seq_len) if window is not None else seq_len
     shape = (batch, Sc, ec.kv_exec, cfg.head_dim)
-    axes = ("batch", "kv_seq", "act_kv", "head_dim")
     return {
-        "k": ParamDef(shape, axes, init="zeros"),
-        "v": ParamDef(shape, axes, init="zeros"),
+        "k": ParamDef(shape, CACHE_AXES, init="zeros"),
+        "v": ParamDef(shape, CACHE_AXES, init="zeros"),
     }

@@ -3,7 +3,9 @@
 The layer stack is a ``lax.scan`` over *pattern periods* (HLO size stays
 O(period) even for 88-layer models), with ``jax.checkpoint`` remat around the
 period body in training. The same ``forward`` serves train, prefill and
-decode; caches thread through the scan as xs/ys.
+decode. Prefill returns a fresh cache per period as the scan's ys; train has
+none. Decode carries the stacked caches through the scan: each layer writes
+its new rows in place and reads its own slice.
 """
 from __future__ import annotations
 
@@ -97,7 +99,8 @@ def forward(
     block_q: int = 512,
     block_k: int = 512,
 ) -> Tuple[jnp.ndarray, Optional[dict], dict]:
-    """Returns (hidden (B,S,D) post-final-norm, new_cache, aux)."""
+    """Returns (hidden (B,S,D) post-final-norm, new_cache, aux). Only decode
+    reads `cache`: the stacked caches of `init_cache_defs`."""
     assert mode in ("train", "prefill", "decode")
     if embeds is None:
         with jax.named_scope("embed"):
@@ -112,8 +115,9 @@ def forward(
     h = shard_constraint(h, ("res_batch", "seq", "embed"), rules, mesh)
     pattern = cfg.layer_pattern
 
-    def apply_layer(h, aux, lp, lc, t):
-        # named scopes ("attention", "mlp") cover each block's norm and residual
+    def apply_layer(h, aux, lp, lc, t, layer=None):
+        # named scopes ("attention", "mlp") cover each block's norm and residual;
+        # in decode `lc` is stacked over periods and `layer` indexes it
         with jax.named_scope("attention" if t.mixer.startswith("attn") else t.mixer):
             resid = h
             hn = rmsnorm(h, lp["norm1"], cfg.norm_eps)
@@ -129,12 +133,19 @@ def forward(
                     window=_layer_window(cfg, t.mixer),
                     mode=mode,
                     cache=lc,
+                    layer=layer,
                     block_q=block_q,
                     block_k=block_k,
                 )
             else:
                 fn = mamba2_apply if cfg.mamba.version == 2 else mamba1_apply
-                y, nc = fn(lp["mixer"], hn, cfg=cfg, rules=rules, mesh=mesh, mode=mode, cache=lc)
+                tm = jax.tree_util.tree_map
+                sl = lc if layer is None else tm(
+                    lambda c: jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False), lc
+                )
+                y, nc = fn(lp["mixer"], hn, cfg=cfg, rules=rules, mesh=mesh, mode=mode, cache=sl)
+                if layer is not None:  # the state is small: write the slice back whole
+                    nc = tm(lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, layer, 0), lc, nc)
             h = resid + y
         if t.ffn != "none":
             with jax.named_scope("mlp" if t.ffn == "dense" else t.ffn):
@@ -156,15 +167,17 @@ def forward(
         apply_layer = jax.checkpoint(apply_layer, static_argnums=(4,))
 
     def body(carry, xs):
-        h, aux = carry
-        pparams, pcache = xs
-        new_pcache = {}
+        h, aux, stacked = carry  # stacked: decode's caches, else empty
+        pparams, layer = xs  # layer: decode's index into them
+        stacked, new_pcache = dict(stacked), {}
         for i, t in enumerate(pattern):
-            lp = pparams[f"pos{i}"]
-            lc = pcache.get(f"pos{i}") if pcache else None
-            h, aux, nc = apply_layer(h, aux, lp, lc, t)
-            if nc is not None:
-                new_pcache[f"pos{i}"] = nc
+            key = f"pos{i}"
+            if mode == "decode":
+                h, aux, stacked[key] = apply_layer(h, aux, pparams[key], stacked[key], t, layer)
+            else:
+                h, aux, nc = apply_layer(h, aux, pparams[key], None, t)
+                if nc is not None:
+                    new_pcache[key] = nc
             if mode == "train" and len(pattern) > 1:
                 # barrier between in-period layers: stops the scheduler from
                 # hoisting every layer's remat-recompute ahead of the layer
@@ -173,14 +186,23 @@ def forward(
         # residual stream at the period boundary: this is what remat saves
         # per scan step — sequence-parallel under training rules
         h = shard_constraint(h, ("res_batch", "seq_res", "embed"), rules, mesh)
-        return (h, aux), new_pcache
+        return (h, aux, stacked), new_pcache
 
     if mode == "train":
         body = jax.checkpoint(body)
 
     aux0 = {"lb": jnp.zeros((), jnp.float32), "z": jnp.zeros((), jnp.float32)}
-    xs = (params["periods"], cache if cache is not None else {})
-    (h, aux), new_cache = jax.lax.scan(body, (h, aux0), xs)
+    if mode == "decode":
+        # the stacked caches ride in the carry, so each layer writes its new
+        # rows into the donated buffers in place: as xs/ys, each layer's slice
+        # would be copied out and back, and the whole stack once more after
+        carry = (h, aux0, dict(cache))
+        layers = jnp.arange(cfg.num_periods, dtype=jnp.int32)
+    else:
+        carry, layers = (h, aux0, {}), None
+    (h, aux, stacked), new_cache = jax.lax.scan(body, carry, (params["periods"], layers))
+    if mode == "decode":
+        new_cache = stacked
     h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
     if mode == "train":
         new_cache = None

@@ -1,14 +1,16 @@
 """Compile-only checks for a described TPU v5e (no chip attached): the
 engine's own decode and prefill steps for h2o-danube-1.8b at its published
-widths in bf16, and the Pallas kernels at danube's shapes. The TPU compiler
-refuses here what it would refuse on the chip: illegal block tiling, more
-memory than a device has.
+widths in bf16, the decode step at Yi-34B's widths, and the Pallas kernels at
+danube's shapes. The TPU compiler refuses here what it would refuse on the
+chip: illegal block tiling, more memory than a device has.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -76,16 +78,18 @@ def _engine_args(cfg, devices, tp, max_tp):
     return store, mesh, cache_ec, storage, caches, rep, econf
 
 
-def test_engine_decode_step_one_chip_fits(topo, danube):
-    store, mesh, cache_ec, storage, caches, rep, econf = _engine_args(
-        danube, topo.devices[:1], tp=1, max_tp=1
-    )
-    step = make_decode_step(danube, store, 1, mesh, cache_ec)
-    compiled = step.lower(
+def _decode_step(cfg, devices, tp):
+    store, mesh, cache_ec, storage, caches, rep, econf = _engine_args(cfg, devices, tp, tp)
+    compiled = make_decode_step(cfg, store, tp, mesh, cache_ec).lower(
         storage, caches,
         jax.ShapeDtypeStruct((econf.n_slots, 1), jnp.int32, sharding=rep),
         jax.ShapeDtypeStruct((econf.n_slots,), jnp.int32, sharding=rep),
     ).compile()
+    return compiled, jax.tree_util.tree_leaves(caches)[0]
+
+
+def test_engine_decode_step_one_chip_fits(topo, danube):
+    compiled, _ = _decode_step(danube, topo.devices[:1], tp=1)
     assert _bytes(compiled) < HBM_BYTES
 
 
@@ -104,17 +108,28 @@ def test_engine_prefill_step_one_chip_fits(topo, danube):
 
 
 def test_engine_decode_step_tp4_on_2x2(topo, danube):
-    store, mesh, cache_ec, storage, caches, rep, econf = _engine_args(
-        danube, topo.devices, tp=4, max_tp=4
-    )
-    step = make_decode_step(danube, store, 4, mesh, cache_ec)
-    compiled = step.lower(
-        storage, caches,
-        jax.ShapeDtypeStruct((econf.n_slots, 1), jnp.int32, sharding=rep),
-        jax.ShapeDtypeStruct((econf.n_slots,), jnp.int32, sharding=rep),
-    ).compile()
+    compiled, _ = _decode_step(danube, topo.devices, tp=4)
     assert _bytes(compiled) < HBM_BYTES
     assert "all-reduce" in compiled.as_text()  # TP 4 reduces partial sums
+
+
+@pytest.mark.parametrize("model,chips", [("danube", 1), ("danube", 4), ("yi_width", 1)])
+def test_decode_step_updates_stacked_caches_in_place(topo, danube, model, chips):
+    """The caches ride through the layer scan in the layout they are stored
+    in: no copy of a whole stacked cache around the loop, and at TP 1 fewer
+    temporary bytes than one stacked K cache (at TP 4 the selected weight
+    shards are temporaries too)."""
+    cfg = danube if model == "danube" else dataclasses.replace(
+        get_config("yi-34b"), num_layers=2)  # Yi-34B widths: heads of 128
+    compiled, k = _decode_step(cfg, topo.devices[:chips], tp=chips)
+    shard = k.sharding.shard_shape(k.shape)
+    stacked = "bf16[" + ",".join(map(str, shard)) + "]"
+    entry = re.search(r"\nENTRY .*?\n}\n", compiled.as_text(), re.S).group(0)
+    copies = [ln for ln in entry.splitlines()
+              if f"= {stacked}" in ln and re.search(r" copy(-start)?\(", ln)]
+    assert not copies, copies
+    if chips == 1:
+        assert compiled.memory_analysis().temp_size_in_bytes < k.size * k.dtype.itemsize
 
 
 def test_paged_attention_compiles_at_danube_shape(topo, danube):

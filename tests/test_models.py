@@ -5,6 +5,8 @@ forward/train step on CPU, and asserts output shapes + finiteness. The
 decode-consistency test is the core serving invariant: prefill(S) followed by
 decode(S) must match a full forward over S+1 tokens.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,8 +21,8 @@ from repro.parallel.sharding import DEFAULT_RULES, make_exec_config
 RULES = DEFAULT_RULES
 
 
-def _setup(name, dtype=jnp.float32):
-    cfg = reduced(get_config(name))
+def _setup(name, dtype=jnp.float32, **overrides):
+    cfg = dataclasses.replace(reduced(get_config(name)), **overrides)
     ec = make_exec_config(cfg, tp=1)
     defs = model_param_defs(cfg, ec)
     params = init_p(defs, jax.random.PRNGKey(0), dtype)
@@ -103,6 +105,66 @@ def test_prefill_decode_matches_full_forward(name):
     np.testing.assert_allclose(
         np.asarray(h_dec[:, 0]), np.asarray(h_full[:, S]), rtol=2e-4, atol=2e-4
     )
+
+
+@pytest.mark.parametrize(
+    "name,head_dim",
+    [(n, None) for n in ASSIGNED_ARCHS] + [("yi-34b", 128), ("h2o-danube-1.8b", 128)],
+)
+def test_decode_writes_one_row_per_layer_in_place(name, head_dim):
+    """Decode returns the caches it was given with one row per layer and slot
+    changed, at the slot's position (its rotating slot in a windowed layer),
+    and that row is what a prefill of one more token writes. A Mamba state is
+    rewritten whole and equals that prefill's state. Heads of 128 fill the
+    TPU's lanes, and take the scatter in place of the row-by-row write."""
+    cfg, ec, params = _setup(name, **({} if head_dim is None else {"head_dim": head_dim}))
+    pos = (7, 19)  # one per slot; 19 wraps a reduced window of 16
+    B, Sc = len(pos), 24
+    key = jax.random.PRNGKey(3)
+    tokens = jax.random.randint(key, (B, max(pos) + 1), 0, cfg.vocab_size)
+    embeds = None
+    if cfg.frontend == "encodec":
+        embeds = jax.random.normal(key, (B, max(pos) + 1, cfg.d_model), jnp.float32) * 0.02
+
+    def prefill(b, n):
+        _, c, _ = forward(
+            params, cfg, ec, rules=RULES, mesh=None, tokens=tokens[b:b + 1, :n],
+            embeds=None if embeds is None else embeds[b:b + 1, :n],
+            mode="prefill", block_q=8, block_k=8,
+        )
+        return c
+
+    # noise everywhere, each slot's prefix cache (or state) over it
+    defs = init_cache_defs(cfg, ec, B, Sc)
+    leaves, tree = jax.tree_util.tree_flatten(defs, is_leaf=lambda d: hasattr(d, "axes"))
+    keys = jax.random.split(jax.random.PRNGKey(4), len(leaves))
+    cache = tree.unflatten([jax.random.normal(k, d.shape) for k, d in zip(keys, leaves)])
+    expect = cache
+    for b, p in enumerate(pos):
+        cache = jax.tree_util.tree_map(
+            lambda c, s: c.at[:, b, : s.shape[2]].set(s[:, 0]), cache, prefill(b, p))
+        expect = jax.tree_util.tree_map(
+            lambda c, s: c.at[:, b, : s.shape[2]].set(s[:, 0]), expect, prefill(b, p + 1))
+
+    last = jnp.stack([tokens[b, p] for b, p in enumerate(pos)])[:, None]
+    _, out, _ = forward(
+        params, cfg, ec, rules=RULES, mesh=None, tokens=last,
+        embeds=None if embeds is None else jnp.stack(
+            [embeds[b, p] for b, p in enumerate(pos)])[:, None],
+        positions=jnp.asarray(pos, jnp.int32), cache=cache, mode="decode",
+    )
+    assert jax.tree_util.tree_structure(out) == jax.tree_util.tree_structure(cache)
+    for key_, c in cache.items():
+        for n, before in c.items():
+            after, want = np.asarray(out[key_][n]), np.asarray(expect[key_][n])
+            if n not in ("k", "v"):  # a Mamba state
+                np.testing.assert_allclose(after, want, rtol=2e-4, atol=2e-4)
+                continue
+            row = np.zeros(after.shape[:3], bool)  # (L, B, Sc)
+            for b, p in enumerate(pos):
+                row[:, b, p % after.shape[2]] = True
+            np.testing.assert_array_equal(after[~row], np.asarray(before)[~row])
+            np.testing.assert_allclose(after[row], want[row], rtol=2e-4, atol=2e-4)
 
 
 def _grow_cache(cfg, cache, extra: int):
