@@ -101,8 +101,8 @@ def measure(spec: dict, cell: dict, seed: int, seconds: float, devices,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     cfg = harness.config_of(spec, cell["config"], root)
     mix = harness.mix_of(cell["traffic"], bench)
-    m = harness.dims_of(cfg)
-    eng = harness.build(cell["config"], cfg, mix, devices, seed)
+    arch, eng = harness.build(cell["config"], cfg, mix, devices, seed, bench)
+    m = arch.dims(cfg)
     harness.log(f"warmup {eng.warmup():.3f} s over TPs {eng.tps}")
     if traffic.tp_at(mix, 0.0) not in (None, eng.tp):
         eng.switch_tp(traffic.tp_at(mix, 0.0))
@@ -125,7 +125,7 @@ def measure(spec: dict, cell: dict, seed: int, seconds: float, devices,
         tracereduce.save(ev, keep)
     tr = enginetrace.EngineReduced(ev)
     d0 = devices[0]
-    ctx = SimpleNamespace(calls=drv.calls, dims=m, chips=len(devices), seconds=seconds,
+    ctx = SimpleNamespace(calls=drv.calls, arch=arch, dims=m, chips=len(devices), seconds=seconds,
                           peaks=peaks_for(d0.device_kind) if d0.platform == "tpu" else None,
                           trace=tr)
     due = [r for r in drv.records.values() if r.due_s < seconds]

@@ -21,12 +21,15 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
-from reference import dense_gqa
-
-# The limit on the widest gap, in logits. It lies between the largest reading
+# The limit on the widest gap, in logits, where a configuration's `check`
+# block gives no `gap_limit` of its own. Each lies between the largest reading
 # of sound bf16 runs of the engine and the smallest of the float8 control, at
-# the configuration's widths; PERF.md gives the readings it was set from.
-GAP_LIMIT = 0.06
+# the configuration's widths; PERF.md gives the readings each was set from.
+DEFAULT_GAP_LIMIT = 0.06
+
+
+def gap_limit(cfg: dict) -> float:
+    return float(cfg["check"].get("gap_limit", DEFAULT_GAP_LIMIT))
 
 
 def sample(finished: Sequence, records: Dict[int, object], switch_s: Sequence[float],
@@ -50,8 +53,9 @@ def sample(finished: Sequence, records: Dict[int, object], switch_s: Sequence[fl
     return chosen
 
 
-def widest_gap(seed, dims, eps, theta, reqs, device, precision="f32", against=None):
-    """Widest gap of the served tokens below the reference's best.
+def widest_gap(seed, arch, cfg, reqs, device, precision="f32", against=None):
+    """Widest gap of the served tokens below the reference's best, by the
+    logits of the configuration's module `arch`.
 
     With `against` (the float32 reference's logits for the same rows), the
     reference itself is computed at `precision` and its own top token is
@@ -60,7 +64,7 @@ def widest_gap(seed, dims, eps, theta, reqs, device, precision="f32", against=No
     """
     seqs = [np.concatenate([r.prompt, np.asarray(r.generated[:-1], np.int32)]) for r in reqs]
     rows = [np.arange(r.prompt_len - 1, r.prompt_len - 1 + len(r.generated)) for r in reqs]
-    lg = dense_gqa.logits(seed, dims, eps, theta, seqs, rows, device, precision)
+    lg = arch.logits(seed, cfg, seqs, rows, device, precision)
     worst, n = 0.0, 0
     for i, r in enumerate(reqs):
         ref = lg[i] if against is None else against[i]

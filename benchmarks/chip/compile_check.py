@@ -2,7 +2,8 @@
 attached, and print each program's bytes per device: the seeded weight
 maker, the engine's decode and prefill steps at the cells' shapes and TPs,
 the cache migration between TPs, and the reference's layer at the check's
-batch. The TPU compiler refuses here what it would refuse on the chip.
+batch where the configuration's module has `compile_layer`. The TPU
+compiler refuses here what it would refuse on the chip.
 
     JAX_PLATFORMS=cpu PYTHONPATH=src python3 benchmarks/chip/compile_check.py
 """
@@ -22,8 +23,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 import harness  # noqa: E402
 import traffic  # noqa: E402
-import weights  # noqa: E402
-from reference import dense_gqa  # noqa: E402
 
 
 def report(what, compiled):
@@ -47,7 +46,7 @@ def engine_programs(name, cfg, mix, devices):
     from repro.parallel.sharding import DEFAULT_RULES, make_exec_config
     from repro.serving.engine import make_decode_step, make_prefill_step
 
-    mcfg = harness.program_config(name, cfg)
+    mcfg = harness.program_config(harness.arch_of(cfg), name, cfg)
     sv = cfg["serving"]
     tps = traffic.tps(mix)
     n_slots = sv["slots_per_chip"] * len(devices)
@@ -79,22 +78,17 @@ def engine_programs(name, cfg, mix, devices):
 
 
 def benchmark_programs(name, cfg, device):
-    one = SingleDeviceSharding(device)
-    m = harness.dims_of(cfg)
-    mcfg = harness.program_config(name, cfg)
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
-    gen = weights.engine_params.lower(key, m, mcfg.vocab_padded).compile()
+    arch = harness.arch_of(cfg)
+    mcfg = harness.program_config(arch, name, cfg)
+    gen = jax.jit(lambda: arch.engine_params(0, cfg, mcfg),
+                  out_shardings=SingleDeviceSharding(device)).lower().compile()
     report(f"{name} seeded weights, one jitted call", gen)
     B, S = cfg["check"]["min_seqs"], cfg["serving"]["max_len"]
-    f32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one)  # noqa: E731
-    w = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one)
-         for k, (s, _) in weights.layer_shapes(m).items()}
-    with jax.default_matmul_precision("highest"):
-        lay = dense_gqa._layer.lower(
-            f32((B, S, m.d_model)), w, jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one),
-            m=m, eps=cfg["rms_norm_eps"], theta=float(cfg["rope_theta"]), precision="f32",
-        ).compile()
-    report(f"{name} reference layer, {B} sequences x {S}", lay)
+    if not hasattr(arch, "compile_layer"):
+        print(f"{name} reference layer: skipped, reference/{cfg['reference']}.py has no "
+              f"compile_layer", flush=True)
+        return
+    report(f"{name} reference layer, {B} sequences x {S}", arch.compile_layer(cfg, device, B, S))
 
 
 def main():

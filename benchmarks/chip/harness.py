@@ -4,8 +4,10 @@ Everything a cell is made of is found by name from `BENCHMARK.json`:
 the configuration's file (`configs[].file`), the traffic mix
 (`traffic/<name>.json`, read by `traffic.generate`), each end-to-end metric
 (`e2e/<name>.py`) and each per-layer metric (`metrics/<name>.py`), and the
-configuration's plain reference (`reference/<name>.py`). A new cell, mix,
-configuration or metric is a new file and a new entry, never an edit here.
+module its configuration names as `reference` (`reference/<name>.py`), the
+one place that knows the architecture (`ARCH`). A new cell, mix,
+configuration, architecture or metric is a new file and a new entry, never
+an edit here.
 A metric module defines `read(ctx)`, which returns a number or None where it
 finds nothing to read; a None leaves the metric out of the line.
 """
@@ -68,32 +70,44 @@ def metrics_for(spec: dict, cell: str, trace: bool):
 
 
 # ---------------------------------------------------------------------------
-# the configuration, for the engine and for the benchmark's own arithmetic
+# the configuration's architecture, for the engine and for the benchmark's
+# own arithmetic
 # ---------------------------------------------------------------------------
-def dims_of(cfg: dict):
-    from counts import Dims
-    heads = cfg["num_attention_heads"]
-    return Dims(
-        layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"], heads=heads,
-        kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg.get("head_dim") or cfg["hidden_size"] // heads,
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        window=cfg.get("sliding_window"),
-    )
+# What a configuration's module, `reference/<cfg["reference"]>.py`, defines:
+#   dims(cfg)                         its shapes, frozen and hashable, with
+#                                     at least `.vocab` and `.layers`
+#   program_config(name, cfg, program)  the engine's ModelConfig, built from
+#                                     `program` (`repro.configs.base`)
+#   engine_params(seed, cfg, mcfg)    the engine's parameter tree, seeded, in
+#                                     the type it is served in
+#   logits(seed, cfg, seqs, rows, device, precision)  the float32 reference's
+#                                     logits at `rows`; precision "fp8" is the
+#                                     control
+#   prefill_flops(dims, prompt_len), decode_flops(dims, contexts),
+#   decode_bytes(dims, contexts, groups), weight_bytes(dims)  the work the
+#                                     roofline shares divide by
+# and may define compile_layer(cfg, device, batch, seq_len) for
+# `compile_check.py`.
+ARCH = ("dims", "program_config", "engine_params", "logits", "prefill_flops",
+        "decode_flops", "decode_bytes", "weight_bytes")
 
 
-def program_config(name: str, cfg: dict):
-    from repro.configs.base import AttnSpec, ModelConfig
-    m = dims_of(cfg)
-    if cfg.get("tie_word_embeddings") or cfg.get("reference") != "dense_gqa":
-        raise ValueError(f"{name}: the harness builds dense GQA decoders with an untied head")
-    return ModelConfig(
-        name=name, family="dense", num_layers=m.layers, d_model=m.d_model,
-        num_heads=m.heads, num_kv_heads=m.kv_heads, head_dim=m.head_dim, d_ff=m.d_ff,
-        vocab_size=m.vocab, norm_eps=cfg["rms_norm_eps"],
-        attn=AttnSpec(kind="swa" if m.window else "full", window=m.window,
-                      rope_theta=float(cfg["rope_theta"])),
-    )
+def arch_of(cfg: dict, bench: Path = BENCH):
+    """The module that knows the configuration's architecture."""
+    path = bench / "reference" / f"{cfg['reference']}.py"
+    if not path.is_file():
+        raise ValueError(f"{cfg.get('name')}: its reference {cfg['reference']!r} names no "
+                         f"module: {path} does not exist")
+    mod = module("reference", cfg["reference"], bench)
+    missing = [f for f in ARCH if not callable(getattr(mod, f, None))]
+    if missing:
+        raise ValueError(f"{path} lacks {', '.join(missing)}")
+    return mod
+
+
+def program_config(arch, name: str, cfg: dict):
+    from repro.configs import base
+    return arch.program_config(name, cfg, base)
 
 
 # ---------------------------------------------------------------------------
@@ -116,19 +130,20 @@ def log(*a) -> None:
     print(*a, file=sys.stderr, flush=True)
 
 
-def build(name: str, cfg: dict, mix: dict, devices, seed: int):
-    """The engine over `devices`, with the benchmark's seeded weights."""
+def build(name: str, cfg: dict, mix: dict, devices, seed: int, bench: Path = BENCH):
+    """(the configuration's module, the engine over `devices` with the
+    benchmark's seeded weights)."""
     import jax
     import jax.numpy as jnp
     import traffic
-    import weights
     from repro.models import model_param_defs
     from repro.models.params import is_def
     from repro.parallel.sharding import make_exec_config
     from repro.serving.engine import EngineConfig, ServingEngine
 
+    arch = arch_of(cfg, bench)
     sv = cfg["serving"]
-    mcfg = program_config(name, cfg)
+    mcfg = program_config(arch, name, cfg)
     tps = traffic.tps(mix)
     if max(tps) > len(devices):
         raise ValueError(f"mix switches to TP {max(tps)} on {len(devices)} devices")
@@ -138,15 +153,20 @@ def build(name: str, cfg: dict, mix: dict, devices, seed: int):
         dtype=jnp.dtype(sv["dtype"]),
     )
     with jax.default_device(devices[0]):
-        params = weights.engine_params(weights.base_key(seed), dims_of(cfg), mcfg.vocab_padded)
+        params = arch.engine_params(seed, cfg, mcfg)
     want = jax.tree_util.tree_map(lambda d: d.shape, model_param_defs(mcfg, make_exec_config(mcfg, 1)),
                                   is_leaf=is_def)
     got = jax.tree_util.tree_map(lambda a: a.shape, params)
     if want != got:
         raise ValueError(f"weights do not match the engine's tree: {got} vs {want}")
+    need = arch.weight_bytes(arch.dims(cfg))
+    held = sum(a.nbytes for a in jax.tree_util.tree_leaves(params))
+    if need > held:  # a decode step cannot read more weights than there are
+        raise ValueError(f"{name}: weight_bytes counts {need} bytes, more than the {held} "
+                         f"the engine holds")
     eng = ServingEngine(mcfg, params, devices, econf)
     del params
-    return eng
+    return arch, eng
 
 
 def run_cell(spec: dict, cell: dict, seed: int, seconds: float, trace: bool, devices,
@@ -166,10 +186,10 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, trace: bool, dev
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     cfg = config_of(spec, cell["config"], root)
     mix = mix_of(cell["traffic"], bench)
-    m = dims_of(cfg)
     sv = cfg["serving"]
     counter = CompileCounter()
-    eng = build(cell["config"], cfg, mix, devices, seed)
+    arch, eng = build(cell["config"], cfg, mix, devices, seed, bench)
+    m = arch.dims(cfg)
     if hooks:
         hooks(eng)
     log(f"warmup {eng.warmup():.3f} s over TPs {eng.tps}")
@@ -216,7 +236,7 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, trace: bool, dev
         except ValueError as e:  # no device ops: the CPU, in tests
             log(f"trace: {e}")
             reduced = None
-        ctx = SimpleNamespace(calls=drv.calls, dims=m, chips=len(devices), seconds=seconds,
+        ctx = SimpleNamespace(calls=drv.calls, arch=arch, dims=m, chips=len(devices), seconds=seconds,
                               peaks=peaks_for(devices[0].device_kind) if reduced else None,
                               trace=reduced)
     metrics = {}
@@ -234,12 +254,12 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, trace: bool, dev
     finished_n = len(drv.finished)
     del eng, drv
     gc.collect()
-    eps, theta = cfg["rms_norm_eps"], float(cfg["rope_theta"])
+    limit = check.gap_limit(cfg)
     if sample:
-        gap, n_tok, ref = check.widest_gap(seed, m, eps, theta, sample, devices[0])
+        gap, n_tok, ref = check.widest_gap(seed, arch, cfg, sample, devices[0])
     else:
         gap, n_tok, ref = math.inf, 0, None
-    correct = bool(n_tok > 0 and gap <= check.GAP_LIMIT)
+    correct = bool(n_tok > 0 and gap <= limit)
     log(f"checked {len(sample)} requests, {n_tok} served tokens "
         f"(longest {max((len(r.generated) for r in sample), default=0)})")
     d0 = devices[0]
@@ -252,16 +272,17 @@ def run_cell(spec: dict, cell: dict, seed: int, seconds: float, trace: bool, dev
                    "memory_peak_bytes": int(peak)},
     }
     if control and ref is not None:  # the float8 reference in the program's place
-        gap_c, _, _ = check.widest_gap(seed, m, eps, theta, sample, devices[0], "fp8", ref)
-        result["control"] = {"correct": bool(gap_c <= check.GAP_LIMIT), "widest_logit_gap": gap_c}
-        log(f"control (float8 reference) widest_logit_gap {gap_c} limit {check.GAP_LIMIT}")
+        gap_c, _, _ = check.widest_gap(seed, arch, cfg, sample, devices[0], "fp8", ref)
+        result["control"] = {"correct": bool(gap_c <= limit), "widest_logit_gap": gap_c,
+                             "limit": limit}
+        log(f"control (float8 reference) widest_logit_gap {gap_c} limit {limit}")
     if trace and ctx.trace:
         result["device"].update(busy_s=ctx.trace.busy_s, window_s=ctx.trace.window_s)
         result["breakdown"] = {"device_ops": ctx.trace.top_ops(), "idle_gaps": ctx.trace.idle_gaps()}
     result["check"] = {"widest_logit_gap": {"value": gap if math.isfinite(gap) else None,
-                                            "limit": check.GAP_LIMIT},
+                                            "limit": limit},
                        "tokens_compared": {"value": n_tok, "limit": 1}}
     log(f"finished {finished_n}; due in window {len(due)}")
-    log(f"check widest_logit_gap {gap} limit {check.GAP_LIMIT}")
+    log(f"check widest_logit_gap {gap} limit {limit}")
     log(f"check tokens_compared {n_tok} limit >= 1")
     return result

@@ -1,12 +1,11 @@
 """Arithmetic shared by the per-layer metric files under `metrics/`.
 
 `ctx` carries the driver's calls (host clock, seconds from the window's
-start), the configuration's `Dims`, the chip's peaks, the chip count and the
-reduced trace. Only calls that started inside the window count.
+start), the configuration's module (`arch`, whose counts give the work) and
+its shapes (`dims`), the chip's peaks, the chip count and the reduced trace.
+Only calls that started inside the window count.
 """
 from __future__ import annotations
-
-import counts
 
 
 def _in(ctx, calls):
@@ -26,15 +25,15 @@ def mean_ms(calls):
 
 
 def prefill_flops(ctx) -> float:
-    return sum(counts.prefill_flops(ctx.dims, c[2]) for c in admits(ctx))
+    return sum(ctx.arch.prefill_flops(ctx.dims, c[2]) for c in admits(ctx))
 
 
 def decode_flops(ctx) -> float:
-    return sum(counts.decode_flops(ctx.dims, c[3]) for c in steps(ctx))
+    return sum(ctx.arch.decode_flops(ctx.dims, c[3]) for c in steps(ctx))
 
 
 def decode_bytes(ctx) -> float:
-    return sum(counts.decode_bytes(ctx.dims, c[3], groups=ctx.chips // (c[2] or 1))
+    return sum(ctx.arch.decode_bytes(ctx.dims, c[3], groups=ctx.chips // (c[2] or 1))
                for c in steps(ctx))
 
 

@@ -21,6 +21,12 @@ float32. Matmuls run at "highest" precision, as float32 on a TPU needs.
 float8 e4m3 (weights per output channel, activations per token, each scaled
 to the format's range) before a float32 product, the step below the bf16
 the configurations state.
+
+This module is also everything the harness knows of the architecture
+(`harness.ARCH`): the shapes of a configuration (`dims`), the engine's
+configuration (`program_config`), the engine's seeded weights
+(`engine_params`), the reference's logits (`logits`) and the work a step
+needs (`counts.py`), plus `compile_layer` for `compile_check.py`.
 """
 from __future__ import annotations
 
@@ -29,7 +35,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
+import counts
+import weights
 from counts import Dims
 from weights import base_key, layer_weights, outer_weights
 
@@ -105,14 +114,14 @@ _make_layer = jax.jit(layer_weights, static_argnames=("m",))
 _make_outer = jax.jit(outer_weights, static_argnames=("m",))
 
 
-def logits(seed: int, m: Dims, eps: float, theta: float, seqs, rows, device,
-           precision: str = "f32"):
+def logits(seed: int, cfg: dict, seqs, rows, device, precision: str = "f32"):
     """Reference logits at chosen positions of each sequence.
 
     seqs: token-id arrays (prompt followed by the served tokens but the last);
     rows: for each sequence, the positions whose next-token logits to return.
     Returns one (len(rows[i]), vocab) float32 array per sequence.
     """
+    m, eps, theta = dims(cfg), cfg["rms_norm_eps"], float(cfg["rope_theta"])
     S = -(-max(len(s) for s in seqs) // Q_CHUNK) * Q_CHUNK
     tok = np.zeros((len(seqs), S), np.int32)
     for i, s in enumerate(seqs):
@@ -131,3 +140,61 @@ def logits(seed: int, m: Dims, eps: float, theta: float, seqs, rows, device,
                                precision=precision))
     splits = np.cumsum([len(r) for r in rows])[:-1]
     return np.split(out, splits)
+
+
+# ---------------------------------------------------------------------------
+# what the harness asks of a configuration's module
+# ---------------------------------------------------------------------------
+def dims(cfg: dict) -> Dims:
+    """The shapes of an HF configuration of this architecture."""
+    heads = cfg["num_attention_heads"]
+    return Dims(
+        layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"], heads=heads,
+        kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg.get("head_dim") or cfg["hidden_size"] // heads,
+        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
+        window=cfg.get("sliding_window"),
+    )
+
+
+def program_config(name: str, cfg: dict, program):
+    """The engine's `ModelConfig`; `program` is the engine's configuration
+    module (`repro.configs.base`), handed in by the harness so that nothing
+    here imports the program."""
+    if cfg.get("tie_word_embeddings"):
+        raise ValueError(f"{name}: dense_gqa draws an untied head")
+    m = dims(cfg)
+    return program.ModelConfig(
+        name=name, family="dense", num_layers=m.layers, d_model=m.d_model,
+        num_heads=m.heads, num_kv_heads=m.kv_heads, head_dim=m.head_dim, d_ff=m.d_ff,
+        vocab_size=m.vocab, norm_eps=cfg["rms_norm_eps"],
+        attn=program.AttnSpec(kind="swa" if m.window else "full", window=m.window,
+                              rope_theta=float(cfg["rope_theta"])),
+    )
+
+
+def engine_params(seed: int, cfg: dict, mcfg):
+    """The engine's parameter tree from the seed, in bfloat16, with the
+    vocabulary padded to the engine's `vocab_padded`."""
+    return weights.engine_params(base_key(seed), dims(cfg), mcfg.vocab_padded)
+
+
+prefill_flops = counts.prefill_flops
+decode_flops = counts.decode_flops
+decode_bytes = counts.decode_bytes
+weight_bytes = counts.weight_bytes
+
+
+def compile_layer(cfg: dict, device, batch: int, seq_len: int):
+    """One float32 layer of the reference, compiled for `device` at `batch`
+    sequences of `seq_len`, from shapes alone."""
+    one = SingleDeviceSharding(device)
+    m = dims(cfg)
+    w = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one)
+         for k, (s, _) in weights.layer_shapes(m).items()}
+    with jax.default_matmul_precision("highest"):
+        return _layer.lower(
+            jax.ShapeDtypeStruct((batch, seq_len, m.d_model), jnp.float32, sharding=one), w,
+            jax.ShapeDtypeStruct((batch, seq_len), jnp.int32, sharding=one),
+            m=m, eps=cfg["rms_norm_eps"], theta=float(cfg["rope_theta"]), precision="f32",
+        ).compile()

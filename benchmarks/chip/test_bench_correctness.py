@@ -52,9 +52,11 @@ def test_engine_passes_and_float8_control_fails(tmp_path, long_batch):
         res = fixture.run(tmp_path / str(seed), "tiny.batch", seconds=6.0, seed=seed,
                           control=True)
         gap = res["check"]["widest_logit_gap"]["value"]
+        limit = res["check"]["widest_logit_gap"]["limit"]
+        assert limit == res["control"]["limit"] == check.DEFAULT_GAP_LIMIT
         assert res["check"]["tokens_compared"]["value"] >= 300
-        assert res["correct"] is True and gap <= check.GAP_LIMIT
-        assert res["control"]["widest_logit_gap"] > check.GAP_LIMIT
+        assert res["correct"] is True and gap <= limit
+        assert res["control"]["widest_logit_gap"] > limit
         assert res["control"]["correct"] is False
 
 
@@ -78,7 +80,7 @@ def _skip_insert(eng):
 def test_a_broken_timed_path_is_not_correct(tmp_path, mid, fault):
     res = fixture.run(tmp_path, "tiny.open", hooks=fault)
     assert res["correct"] is False
-    assert res["check"]["widest_logit_gap"]["value"] > check.GAP_LIMIT
+    assert res["check"]["widest_logit_gap"]["value"] > res["check"]["widest_logit_gap"]["limit"]
 
 
 FOUR = """
@@ -100,7 +102,7 @@ out = {{}}
 for name, hook in (("sound", None), ("no_exchange", skip_exchange)):
     res = fixture.run(Path(tempfile.mkdtemp()), "tiny.open", seconds=2.5, chips=4, hooks=hook)
     out[name] = [res["correct"], res["check"]["widest_logit_gap"]["value"],
-                 res["device"]["count"]]
+                 res["device"]["count"], res["check"]["widest_logit_gap"]["limit"]]
 print(json.dumps(out))
 """
 
@@ -115,7 +117,7 @@ def test_the_exchange_between_chips_left_out_is_not_correct():
     assert out.returncode == 0, out.stderr[-3000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["sound"][0] is True and res["sound"][2] == 4
-    assert res["no_exchange"][0] is False and res["no_exchange"][1] > check.GAP_LIMIT
+    assert res["no_exchange"][0] is False and res["no_exchange"][1] > res["no_exchange"][3]
 
 
 def test_sample_holds_the_longest_and_one_request_spanning_each_switch():

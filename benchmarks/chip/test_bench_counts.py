@@ -10,7 +10,8 @@ HERE = Path(__file__).resolve().parent
 
 
 def dims(name):
-    return harness.dims_of(json.loads((HERE / "configs" / f"{name}.json").read_text()))
+    cfg = json.loads((HERE / "configs" / f"{name}.json").read_text())
+    return harness.arch_of(cfg).dims(cfg)
 
 
 # h2o-danube-1.8b (huggingface.co/h2oai/h2o-danube-1.8b-base), at full size

@@ -65,11 +65,13 @@ def test_four_devices_served_metrics_and_switches_checked(variant):
     res = out["res"]
     assert res["device"]["count"] == 4 and res["failed"] == 0
     assert res["correct"] is True, res["check"]
-    assert res["check"]["widest_logit_gap"]["value"] <= check.GAP_LIMIT
+    limit = res["check"]["widest_logit_gap"]["limit"]
+    assert limit == check.DEFAULT_GAP_LIMIT
+    assert res["check"]["widest_logit_gap"]["value"] <= limit
     assert set(res["metrics"]) == SERVED | {"setup_s"}
     assert all(v["value"] > 0 for v in res["metrics"].values())
     spanned = [s for s in out["switches"] if s[2]]
     assert len(spanned) >= 2, out["switches"]  # both directions, each crossed by a request
     assert all(s[1] for s in spanned)  # and each such switch has one in the sample
     assert res["control"]["correct"] is False  # the float8 reference in the engine's place
-    assert res["control"]["widest_logit_gap"] > check.GAP_LIMIT
+    assert res["control"]["widest_logit_gap"] > limit and res["control"]["limit"] == limit
