@@ -22,7 +22,7 @@ from repro.parallel.sharding import ExecConfig, pspec_for, shard_constraint
 
 NEG_INF = -1e30
 LANES = 128  # a TPU vector register's lanes: the minor tile of every layout
-CACHE_AXES = ("batch", "kv_seq", "act_kv", "head_dim")  # one layer's K or V
+CACHE_AXES = ("batch", "act_kv", "kv_seq", "head_dim")  # one layer's K or V
 
 
 def attn_param_defs(cfg: ModelConfig, ec: ExecConfig) -> dict:
@@ -114,7 +114,7 @@ def _blockwise(q, k, v, q_pos, k_pos, *, window, cap, block_q, block_k):
 
 
 def decode_attention(q, k_cache, v_cache, valid, cap, rules, mesh):
-    """q: (B,KV,G,hd); caches: (B,S,KV,hd); valid: (B,S) bool -> (B,KV,G,hd).
+    """q: (B,KV,G,hd); caches: (B,KV,S,hd); valid: (B,S) bool -> (B,KV,G,hd).
 
     Under `kv_seq -> data` rules this is split-KV (context-parallel) decode:
     the softmax max/sum and the PV contraction reduce over the sharded S axis
@@ -122,7 +122,7 @@ def decode_attention(q, k_cache, v_cache, valid, cap, rules, mesh):
     """
     scale = q.shape[-1] ** -0.5
     s = jnp.einsum(
-        "bkgh,bskh->bkgs", q, k_cache, preferred_element_type=jnp.float32
+        "bkgh,bksh->bkgs", q, k_cache, preferred_element_type=jnp.float32
     ) * scale
     if cap is not None:
         s = cap * jnp.tanh(s / cap)
@@ -132,7 +132,7 @@ def decode_attention(q, k_cache, v_cache, valid, cap, rules, mesh):
     p = jnp.exp(s - m)
     l = p.sum(-1, keepdims=True)
     o = jnp.einsum(
-        "bkgs,bskh->bkgh", p / jnp.maximum(l, 1e-30), v_cache,
+        "bkgs,bksh->bkgh", p / jnp.maximum(l, 1e-30), v_cache,
         preferred_element_type=jnp.float32,
     )
     return o
@@ -156,7 +156,7 @@ def attn_apply(
     positions,  # (S,) for train/prefill; (B,) for decode
     window: Optional[int],
     mode: str,  # train | prefill | decode
-    cache: Optional[dict] = None,  # decode: {"k": (L,B,Sc,KV,hd), "v": ...} over periods
+    cache: Optional[dict] = None,  # decode: {"k": (L,B,KV,Sc,hd), "v": ...} over periods
     layer=None,  # decode: this layer's index into the stacked cache
     block_q: int = 512,
     block_k: int = 512,
@@ -192,22 +192,17 @@ def attn_apply(
         ).astype(x.dtype)
         new_cache = None
         if mode == "prefill":
-            with jax.named_scope("kv_write"):
+            with jax.named_scope("kv_write"):  # stored head-major: (B,KV,S,hd)
+                kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
                 if window is not None and S > window:
                     slots = swa_cache_slots(window, S)
-                    ck = jnp.zeros((B, window, KV, hd), k.dtype).at[:, slots].set(
-                        k[:, -window:]
-                    )
-                    cv = jnp.zeros((B, window, KV, hd), v.dtype).at[:, slots].set(
-                        v[:, -window:]
-                    )
-                    new_cache = {"k": ck, "v": cv}
-                else:
-                    new_cache = {"k": k, "v": v}
+                    kh, vh = (jnp.zeros((B, KV, window, hd), c.dtype).at[:, :, slots].set(
+                        c[:, :, -window:]) for c in (kh, vh))
+                new_cache = {"k": kh, "v": vh}
         o = o.reshape(B, S, ec.heads_exec, hd)
     else:
         assert cache is not None and layer is not None
-        Sc = cache["k"].shape[2]
+        Sc = cache["k"].shape[3]
         if window is not None:
             slot = positions % window
             written_all = positions >= window
@@ -233,32 +228,37 @@ def attn_apply(
 
 
 def _write_rows(cache, rows, slot, layer, rules, mesh):
-    """`cache` (L,B,Sc,KV,hd), stacked over periods, with each slot's row of
-    `rows` (B,KV,hd) written in place at [layer, b, slot[b]].
+    """`cache` (L,B,KV,Sc,hd), stacked over periods, with each slot's row of
+    `rows` (B,KV,hd) written in place at [layer, b, :, slot[b]].
 
     The scan over layers carries the cache in the layout its body asks for,
     and copies the whole stack in and out where that is not the stored one.
-    A TPU stores a cache whose head_dim fills whole 128-lane tiles row-major,
-    and any other with the sequence on the lanes. A scatter asks for its rows
-    on the lanes, which keeps the first. Row-by-row updates ask for nothing,
-    so the carry takes the layout in which the attention reads its slice,
-    which for the second is the stored one (tests/test_chip_compile.py checks
-    both for a v5e). They run per device, so no slot's row crosses chips.
+    So the write's window and the attention's read must both keep (Sc, hd)
+    minor: the attention's two products read each (b, kv) slab as (Sc, hd)
+    tiles, and a write whose window spans the heads, (KV, hd), would pin the
+    heads minor instead. Where head_dim fills whole 128-lane tiles the rows
+    are scattered one `hd` row per (slot, head); else they are written slot
+    by slot, which asks for no layout. The row-by-row writes run per device,
+    so no slot's row crosses chips (tests/test_chip_compile.py checks both
+    for a v5e).
     """
     if rows.shape[-1] % LANES == 0:
-        return cache.at[layer, jnp.arange(rows.shape[0]), slot].set(rows)
+        B, KV = rows.shape[:2]
+        return cache.at[
+            layer, jnp.arange(B)[:, None], jnp.arange(KV)[None, :], slot[:, None]
+        ].set(rows)
 
     def by_row(c, r, s, layer):
         for b in range(r.shape[0]):
-            c = jax.lax.dynamic_update_slice(c, r[b][None, None, None], (layer, b, s[b], 0, 0))
+            c = jax.lax.dynamic_update_slice(c, r[b][None, None, :, None], (layer, b, 0, s[b], 0))
         return c
 
     spec = pspec_for(("periods",) + CACHE_AXES, rules, mesh)
-    if mesh is None or spec[2] is not None:  # a sharded sequence: left to the partitioner
+    if mesh is None or spec[3] is not None:  # a sharded sequence: left to the partitioner
         return by_row(cache, rows, slot, layer)
     return jax.shard_map(
         by_row, mesh=mesh,
-        in_specs=(spec, P(spec[1], spec[3], spec[4]), P(spec[1]), P()),
+        in_specs=(spec, P(spec[1], spec[2], spec[4]), P(spec[1]), P()),
         out_specs=spec, check_vma=False,
     )(cache, rows, slot, layer)
 
@@ -266,7 +266,7 @@ def _write_rows(cache, rows, slot, layer, rules, mesh):
 def attn_cache_defs(cfg: ModelConfig, ec: ExecConfig, batch: int, seq_len: int, window):
     """Cache ParamDefs for one attention layer (no leading period dim)."""
     Sc = min(window, seq_len) if window is not None else seq_len
-    shape = (batch, Sc, ec.kv_exec, cfg.head_dim)
+    shape = (batch, ec.kv_exec, Sc, cfg.head_dim)
     return {
         "k": ParamDef(shape, CACHE_AXES, init="zeros"),
         "v": ParamDef(shape, CACHE_AXES, init="zeros"),

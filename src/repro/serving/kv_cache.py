@@ -34,7 +34,7 @@ class SlotCache:
     ec: ExecConfig
     n_slots: int
     max_len: int
-    arrays: dict = None  # pytree: {"pos{i}": {...: (P, B, S, KV, hd)}}
+    arrays: dict = None  # pytree: {"pos{i}": {"k"/"v": (P, B, KV, S, hd)}}
     lengths: np.ndarray = None  # host-side per-slot lengths
     free: Deque[int] = None
 

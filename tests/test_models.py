@@ -134,7 +134,8 @@ def test_decode_writes_one_row_per_layer_in_place(name, head_dim):
         )
         return c
 
-    # noise everywhere, each slot's prefix cache (or state) over it
+    # noise everywhere, each slot's prefix cache (or state) over it; every
+    # leaf is (L, B, ...), and a K/V cache (L, B, KV, Sc, hd)
     defs = init_cache_defs(cfg, ec, B, Sc)
     leaves, tree = jax.tree_util.tree_flatten(defs, is_leaf=lambda d: hasattr(d, "axes"))
     keys = jax.random.split(jax.random.PRNGKey(4), len(leaves))
@@ -142,9 +143,9 @@ def test_decode_writes_one_row_per_layer_in_place(name, head_dim):
     expect = cache
     for b, p in enumerate(pos):
         cache = jax.tree_util.tree_map(
-            lambda c, s: c.at[:, b, : s.shape[2]].set(s[:, 0]), cache, prefill(b, p))
+            lambda c, s: c.at[:, b, :, : s.shape[3]].set(s[:, 0]), cache, prefill(b, p))
         expect = jax.tree_util.tree_map(
-            lambda c, s: c.at[:, b, : s.shape[2]].set(s[:, 0]), expect, prefill(b, p + 1))
+            lambda c, s: c.at[:, b, :, : s.shape[3]].set(s[:, 0]), expect, prefill(b, p + 1))
 
     last = jnp.stack([tokens[b, p] for b, p in enumerate(pos)])[:, None]
     _, out, _ = forward(
@@ -160,16 +161,16 @@ def test_decode_writes_one_row_per_layer_in_place(name, head_dim):
             if n not in ("k", "v"):  # a Mamba state
                 np.testing.assert_allclose(after, want, rtol=2e-4, atol=2e-4)
                 continue
-            row = np.zeros(after.shape[:3], bool)  # (L, B, Sc)
+            row = np.zeros(after.shape[:4], bool)  # (L, B, KV, Sc)
             for b, p in enumerate(pos):
-                row[:, b, p % after.shape[2]] = True
+                row[:, b, :, p % after.shape[3]] = True
             np.testing.assert_array_equal(after[~row], np.asarray(before)[~row])
             np.testing.assert_allclose(after[row], want[row], rtol=2e-4, atol=2e-4)
 
 
 def _grow_cache(cfg, cache, extra: int):
-    """Pad attention KV caches with `extra` free slots (windowed caches are
-    rotating buffers and never grow)."""
+    """Pad attention KV caches (L, B, KV, S, hd) with `extra` free slots
+    (windowed caches are rotating buffers and never grow)."""
     out = {}
     for pos, c in cache.items():
         if "k" in c:  # attention
@@ -178,10 +179,10 @@ def _grow_cache(cfg, cache, extra: int):
             window = cfg.attn.window if (
                 t.mixer == "attn_local" or (t.mixer == "attn" and cfg.attn.kind == "swa")
             ) else None
-            if window is not None and c["k"].shape[2] >= window:
+            if window is not None and c["k"].shape[3] >= window:
                 out[pos] = c
             else:
-                pad = [(0, 0), (0, 0), (0, extra), (0, 0), (0, 0)]
+                pad = [(0, 0), (0, 0), (0, 0), (0, extra), (0, 0)]
                 out[pos] = {k: jnp.pad(v, pad) for k, v in c.items()}
         else:
             out[pos] = c
