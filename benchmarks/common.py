@@ -19,7 +19,6 @@ from repro.profiles.perf_model import PerfModel
 from repro.profiles.slo import derive_tiers
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results")
-DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "dryrun_results")
 
 MODEL = "llama3-8b"
 N_CHIPS = 16

@@ -35,7 +35,6 @@ MODULES = [
     "scenario_matrix",
     "fault_matrix",
     "cascade_matrix",
-    "roofline_table",
 ]
 
 

@@ -59,8 +59,8 @@ def _reference_fn(cfg):
 
     @jax.jit
     def ref(params, tokens):
-        h, _, _ = forward(params, cfg, ec, rules=DEFAULT_RULES, mesh=None,
-                          tokens=tokens, mode="train")
+        h, _ = forward(params, cfg, ec, rules=DEFAULT_RULES, mesh=None,
+                       tokens=tokens, mode="prefill")
         return logits_for(params, cfg, h, DEFAULT_RULES, None)[0, :, : cfg.vocab_size]
 
     return ref

@@ -1,4 +1,4 @@
-"""Quickstart: build a model, run forward/prefill/decode, train a few steps.
+"""Quickstart: build a model, run a prefill and one decode step.
 
     PYTHONPATH=src python examples/quickstart.py
 """
@@ -7,18 +7,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced
-from repro.models import forward, model_param_defs
+from repro.models import forward, init_cache_defs, model_param_defs
 from repro.models.model import logits_for
 from repro.models.params import count_params, init_params
 from repro.parallel.sharding import DEFAULT_RULES, make_exec_config
-from repro.training.data import SyntheticDataset
-from repro.training.optimizer import AdamWConfig
-from repro.training.train_step import TrainStepConfig, init_opt_state, make_train_step
 
 
 def main() -> None:
-    # Any assigned architecture works: --full configs are exercised via the
-    # dry-run; on CPU we use the reduced same-family config.
+    # Any assigned architecture works; on CPU we use the reduced
+    # same-family config.
     cfg = reduced(get_config("gemma2-2b"))
     ec = make_exec_config(cfg, tp=1)
     defs = model_param_defs(cfg, ec)
@@ -30,33 +27,23 @@ def main() -> None:
     tokens = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
 
     # prefill + one decode step
-    h, cache, _ = forward(params, cfg, ec, rules=DEFAULT_RULES, mesh=None,
-                          tokens=tokens, mode="prefill", block_q=16, block_k=16)
+    h, cache = forward(params, cfg, ec, rules=DEFAULT_RULES, mesh=None,
+                       tokens=tokens, mode="prefill", block_q=16, block_k=16)
     logits = logits_for(params, cfg, h[:, -1:], DEFAULT_RULES, None)
     nxt = jnp.argmax(logits[:, 0, : cfg.vocab_size], -1)
     print("prefill ok; first sampled tokens:", np.asarray(nxt))
 
+    # the decode step reads caches of its own capacity: copy the prefill's
+    # into zero caches one token longer (a windowed layer keeps its window)
+    room = init_params(init_cache_defs(cfg, ec, B, S + 1), jax.random.PRNGKey(2), jnp.float32)
     cache = jax.tree_util.tree_map(
-        lambda x: jnp.pad(x, [(0, 0)] * 2 + [(0, 8 if x.ndim == 5 else 0)] + [(0, 0)] * (x.ndim - 3))
-        if x.ndim == 5 else x,
-        cache,
+        lambda c, p: c.at[:, :, :, : p.shape[3]].set(p) if c.ndim == 5 else p, room, cache
     )
-    h, cache, _ = forward(params, cfg, ec, rules=DEFAULT_RULES, mesh=None,
-                          tokens=nxt[:, None].astype(jnp.int32),
-                          positions=jnp.full((B,), S, jnp.int32),
-                          cache=cache, mode="decode")
+    h, cache = forward(params, cfg, ec, rules=DEFAULT_RULES, mesh=None,
+                       tokens=nxt[:, None].astype(jnp.int32),
+                       positions=jnp.full((B,), S, jnp.int32),
+                       cache=cache, mode="decode")
     print("decode ok; hidden:", h.shape)
-
-    # a few train steps
-    tcfg = TrainStepConfig(opt=AdamWConfig(lr=3e-3, warmup_steps=5),
-                           seq_chunk=16, block_q=16, block_k=16)
-    step_fn, _ = make_train_step(cfg, ec, DEFAULT_RULES, None, tcfg)
-    opt = init_opt_state(params, tcfg)
-    ds = SyntheticDataset(cfg, batch=4, seq=32)
-    for i in range(10):
-        params, opt, m = step_fn(params, opt, ds.at(i))
-        if i % 3 == 0:
-            print(f"train step {i}: loss {float(m['loss']):.4f}")
     print("quickstart done")
 
 

@@ -1,16 +1,13 @@
 from repro.configs.base import (
-    SHAPES,
     AttnSpec,
     LayerTemplate,
     MambaSpec,
     ModelConfig,
     MoESpec,
-    ShapeSpec,
     get_config,
     list_configs,
     reduced,
     register,
-    shape_applicable,
 )
 
 ASSIGNED_ARCHS = (
@@ -27,17 +24,14 @@ ASSIGNED_ARCHS = (
 )
 
 __all__ = [
-    "SHAPES",
     "AttnSpec",
     "LayerTemplate",
     "MambaSpec",
     "ModelConfig",
     "MoESpec",
-    "ShapeSpec",
     "get_config",
     "list_configs",
     "reduced",
     "register",
-    "shape_applicable",
     "ASSIGNED_ARCHS",
 ]

@@ -37,8 +37,6 @@ class MoESpec:
     d_ff_expert: int
     num_shared_experts: int = 0
     capacity_factor: float = 1.25
-    router_aux_weight: float = 0.01
-    router_z_weight: float = 1e-3
     moe_every: int = 1  # 1 = every FFN is MoE; 2 = alternate dense/MoE
 
 
@@ -82,7 +80,6 @@ class ModelConfig:
     final_logit_softcap: Optional[float] = None
     tie_embeddings: bool = False
     frontend: Optional[str] = None  # "vq_image" | "encodec" (stub embeddings)
-    subquadratic: bool = False  # eligible for long_500k
     source: str = ""  # citation tag
 
     # ---- derived -----------------------------------------------------
@@ -178,33 +175,6 @@ class ModelConfig:
 
 def ceil_to(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
-
-
-# ---------------------------------------------------------------------------
-# Input shapes assigned to the LM family (seq_len x global_batch).
-# decode_* / long_* lower serve_step (one token + KV cache), not train_step.
-# ---------------------------------------------------------------------------
-@dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-
-SHAPES = {
-    "train_4k": ShapeSpec("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeSpec("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeSpec("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeSpec("long_500k", 524_288, 1, "decode"),
-}
-
-
-def shape_applicable(cfg: ModelConfig, shape: ShapeSpec) -> bool:
-    """long_500k needs sub-quadratic attention (see DESIGN.md §7)."""
-    if shape.name == "long_500k":
-        return cfg.subquadratic
-    return True
 
 
 # ---------------------------------------------------------------------------

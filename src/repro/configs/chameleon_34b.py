@@ -19,7 +19,6 @@ CONFIG = register(
         vocab_size=65536,
         attn=AttnSpec(kind="full", rope_theta=10_000.0, qk_norm=True),
         frontend="vq_image",
-        subquadratic=False,
         source="arXiv:2405.09818",
     )
 )

@@ -14,7 +14,6 @@ CONFIG = register(
         vocab_size=100352,
         attn=AttnSpec(kind="full", rope_theta=500_000.0),
         moe=MoESpec(num_experts=16, top_k=4, d_ff_expert=10752),
-        subquadratic=False,
         source="arXiv:2405... hf:databricks/dbrx-base",
     )
 )

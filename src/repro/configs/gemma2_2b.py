@@ -23,7 +23,6 @@ CONFIG = register(
         tie_embeddings=True,
         # 13 local + 13 global alternating layers; global layers use
         # context-parallel KV for long decode => eligible for long_500k.
-        subquadratic=True,
         source="arXiv:2408.00118; hf",
     )
 )

@@ -14,7 +14,6 @@ CONFIG = register(
         d_ff=6912,
         vocab_size=32000,
         attn=AttnSpec(kind="swa", window=4096, rope_theta=10_000.0),
-        subquadratic=True,  # SWA => bounded KV; long_500k runs
         source="arXiv:2401.16818; hf",
     )
 )

@@ -35,12 +35,10 @@ CONFIG = register(
         vocab_size=65536,
         attn=AttnSpec(kind="full", rope_theta=10_000.0),
         moe=MoESpec(num_experts=16, top_k=2, d_ff_expert=14336, moe_every=2),
-        # chunk=64 bounds the selective-scan backward working set (the
-        # associative scan saves its tree levels per chunk): §Perf jamba
-        # train iteration 2
+        # chunk=64 bounds the selective scan's per-chunk working set (the
+        # associative scan holds its tree levels per chunk)
         mamba=MambaSpec(version=1, d_state=16, d_conv=4, expand=2, chunk=64),
         pattern=_PATTERN,
-        subquadratic=True,  # only 4/32 layers keep full KV
         source="arXiv:2403.19887; hf",
     )
 )

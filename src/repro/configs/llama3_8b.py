@@ -13,7 +13,6 @@ CONFIG = register(
         d_ff=14336,
         vocab_size=128256,
         attn=AttnSpec(kind="full", rope_theta=500_000.0),
-        subquadratic=False,
         source="arXiv:2407.21783",
     )
 )

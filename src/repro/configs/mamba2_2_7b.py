@@ -18,7 +18,6 @@ CONFIG = register(
         d_ff=0,
         vocab_size=50280,
         mamba=MambaSpec(version=2, d_state=128, d_conv=4, expand=2, head_dim=64, ngroups=1),
-        subquadratic=True,
         source="arXiv:2405.21060",
     )
 )

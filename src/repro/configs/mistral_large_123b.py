@@ -13,7 +13,6 @@ CONFIG = register(
         d_ff=28672,
         vocab_size=32768,
         attn=AttnSpec(kind="full", rope_theta=1_000_000.0),
-        subquadratic=False,
         source="hf:mistralai/Mistral-Large-Instruct-2407",
     )
 )

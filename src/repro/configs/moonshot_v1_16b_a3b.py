@@ -14,7 +14,6 @@ CONFIG = register(
         vocab_size=163840,
         attn=AttnSpec(kind="full", rope_theta=50_000.0),
         moe=MoESpec(num_experts=64, top_k=6, d_ff_expert=1408),
-        subquadratic=False,
         source="hf:moonshotai/Moonlight-16B-A3B",
     )
 )

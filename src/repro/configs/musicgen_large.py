@@ -19,7 +19,6 @@ CONFIG = register(
         vocab_size=2048,
         attn=AttnSpec(kind="full", rope_theta=10_000.0),
         frontend="encodec",
-        subquadratic=False,
         source="arXiv:2306.05284; hf",
     )
 )

@@ -13,7 +13,6 @@ CONFIG = register(
         d_ff=20480,
         vocab_size=64000,
         attn=AttnSpec(kind="full", rope_theta=5_000_000.0),
-        subquadratic=False,
         source="arXiv:2403.04652; hf",
     )
 )

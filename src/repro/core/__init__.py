@@ -2,7 +2,6 @@
 
   weight_store — storage-TP weight layout whose per-device bytes are
       identical at every execution TP level (zero-copy TP switching).
-  tp_switch    — AOT executable cache per TP level + switch controller.
   migration    — KV/state re-partitioning plans and collective programs.
   planner      — goodput-efficiency estimation + weighted greedy GPU
       assignment (paper §3.3.1).

@@ -13,7 +13,7 @@ TPU realization:
     all-to-all);
   * the analytic latency model below reproduces the paper's Fig. 7
     (naive per-page vs aggregated vs pipelined) for the simulator and
-    benchmark; on-chip numbers come from the dry-run roofline constants.
+    benchmark.
 
 Paper-inapplicability note (DESIGN.md §7): mamba2 has no KV cache; its
 analogue is the O(1)-per-sequence SSD state, migrated the same way (and two
@@ -23,13 +23,11 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Optional
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
-from repro.configs.base import ModelConfig
 from repro.models.params import is_def
 from repro.parallel.sharding import ShardingRules, pspec_for
 from repro.profiles.perf_model import HardwareSpec, V5E
@@ -44,10 +42,13 @@ def cache_shardings(cache_defs, rules: ShardingRules, mesh: Mesh):
 
 
 class MigrationAborted(RuntimeError):
-    """The source cache is guaranteed untouched: migration is functional
-    (device_put builds new arrays; nothing frees or mutates the source
-    until the caller drops its reference), so after an abort the caller
-    can retry on a reduced pool or restart the sequences from scratch."""
+    """A cache migration, or the TP switch around it, failed. The source
+    cache is guaranteed untouched: migration is functional (device_put
+    builds new arrays; nothing frees or mutates the source until the caller
+    drops its reference), so after an abort the caller can keep serving at
+    the old TP, retry on a reduced pool or restart the sequences from
+    scratch. `ServingEngine` has put its weight binding back before this
+    reaches its caller."""
 
 
 def reshard(tree, target_shardings):
@@ -151,28 +152,3 @@ class MigrationModel:
             "pipelined": self.pipelined_s,
         }[strategy](total_bytes)
 
-
-def kv_migration_bytes(
-    cfg: ModelConfig, n_seqs: int, ctx_len: int, from_tp: int, to_tp: int,
-    dtype_bytes: int = 2,
-) -> float:
-    """Bytes that must cross chips when re-partitioning KV heads.
-
-    Head-repartitioning moves the fraction of heads whose owner changes;
-    upper bound (paper's Fig. 6 worst case) is the full per-group cache.
-    """
-    if cfg.n_attn_layers == 0:
-        # SSM: migrate recurrent state instead
-        from repro.profiles.perf_model import PerfModel
-
-        return n_seqs * PerfModel(cfg).state_bytes()
-    win = cfg.attn.window or ctx_len
-    eff = min(ctx_len, win)
-    per_seq = 2 * cfg.num_kv_heads * cfg.head_dim * dtype_bytes * eff * cfg.n_attn_layers
-    lo, hi = min(from_tp, to_tp), max(from_tp, to_tp)
-    moved_frac = 1.0 - lo / hi  # heads staying on the same chip
-    if cfg.mamba is not None:  # hybrid: add state bytes
-        from repro.profiles.perf_model import PerfModel
-
-        per_seq += PerfModel(cfg).state_bytes()
-    return n_seqs * per_seq * moved_frac

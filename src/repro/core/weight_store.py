@@ -47,7 +47,7 @@ def model_dim_of(d: ParamDef, rules: ShardingRules) -> Optional[int]:
     return dims[0] if dims else None
 
 
-def make_exec_mesh(devices: Sequence, tp: int, with_pod: bool = False) -> Mesh:
+def make_exec_mesh(devices: Sequence, tp: int) -> Mesh:
     """Model-major mesh: device d gets model coordinate floor(d*tp/N)."""
     n = len(devices)
     assert n % tp == 0, (n, tp)

@@ -1,7 +1,6 @@
 from repro.models.params import ParamDef, init_params, param_shape_structs, param_shardings
 from repro.models.model import (
     forward,
-    loss_fn,
     model_param_defs,
     init_cache_defs,
 )
@@ -12,7 +11,6 @@ __all__ = [
     "param_shape_structs",
     "param_shardings",
     "forward",
-    "loss_fn",
     "model_param_defs",
     "init_cache_defs",
 ]

@@ -1,11 +1,10 @@
 """Attention: GQA with full / sliding-window / local-global(+softcap) variants.
 
-Train/prefill use a blockwise (flash-style) streaming softmax over KV blocks
-inside a scan over Q blocks — activation memory is O(S·block), which makes the
-32k prefill shapes compilable at 16 GB/chip. Decode is a single-token gather
-over the cache; with ``kv_seq -> data`` sharding rules the same code becomes
-context-parallel split-KV decode (XLA inserts the LSE-combining all-reduces),
-which is how ``long_500k`` runs.
+Prefill uses a blockwise (flash-style) streaming softmax over KV blocks
+inside a scan over Q blocks — activation memory is O(S·block), which makes
+long prefills compilable at 16 GB/chip. Decode is a single-token gather over
+the cache; with ``kv_seq -> data`` sharding rules the same code becomes
+context-parallel split-KV decode (XLA inserts the LSE-combining all-reduces).
 """
 from __future__ import annotations
 
@@ -71,15 +70,9 @@ def _blockwise(q, k, v, q_pos, k_pos, *, window, cap, block_q, block_k):
     vb = v.reshape(B, nk, bk, KV, hd).transpose(1, 0, 2, 3, 4)
     kpb = k_pos.reshape(nk, bk)
 
-    @jax.checkpoint  # recompute the KV scan in bwd: avoids saving every
-    # (bq x bk) softmax block — the difference between O(S·bq) and O(S²/blk)
-    # attention residency under layer-level remat
     def q_step(_, q_in):
         q_i, qp = q_in  # (B,bq,KV,G,hd), (bq,)
 
-        @jax.checkpoint  # flash-bwd: recompute s/p per block in the backward
-        # pass instead of saving score-sized f32 residuals (the dominant HBM
-        # term otherwise — see EXPERIMENTS.md §Perf)
         def kv_step(carry, kv_in):
             m, l, acc = carry
             k_j, v_j, kp = kv_in  # (B,bk,KV,hd), (bk,)
@@ -153,14 +146,14 @@ def attn_apply(
     ec: ExecConfig,
     rules,
     mesh,
-    positions,  # (S,) for train/prefill; (B,) for decode
+    positions,  # (S,) for prefill; (B,) for decode
     window: Optional[int],
-    mode: str,  # train | prefill | decode
+    mode: str,  # prefill | decode
     cache: Optional[dict] = None,  # decode: {"k": (L,B,KV,Sc,hd), "v": ...} over periods
     layer=None,  # decode: this layer's index into the stacked cache
     block_q: int = 512,
     block_k: int = 512,
-) -> Tuple[jnp.ndarray, Optional[dict]]:
+) -> Tuple[jnp.ndarray, dict]:
     B = x.shape[0]
     hd = cfg.head_dim
     KV, G = ec.kv_exec, ec.q_per_kv
@@ -183,22 +176,20 @@ def attn_apply(
     q = apply_rope(q, rope_pos, cfg.attn.rope_theta)
     k = apply_rope(k, rope_pos, cfg.attn.rope_theta)
 
-    if mode in ("train", "prefill"):
+    if mode == "prefill":
         S = x.shape[1]
         qg = q.reshape(B, S, KV, G, hd)
         o = _blockwise(
             qg, k, v, positions, positions,
             window=window, cap=cap, block_q=block_q, block_k=block_k,
         ).astype(x.dtype)
-        new_cache = None
-        if mode == "prefill":
-            with jax.named_scope("kv_write"):  # stored head-major: (B,KV,S,hd)
-                kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-                if window is not None and S > window:
-                    slots = swa_cache_slots(window, S)
-                    kh, vh = (jnp.zeros((B, KV, window, hd), c.dtype).at[:, :, slots].set(
-                        c[:, :, -window:]) for c in (kh, vh))
-                new_cache = {"k": kh, "v": vh}
+        with jax.named_scope("kv_write"):  # stored head-major: (B,KV,S,hd)
+            kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+            if window is not None and S > window:
+                slots = swa_cache_slots(window, S)
+                kh, vh = (jnp.zeros((B, KV, window, hd), c.dtype).at[:, :, slots].set(
+                    c[:, :, -window:]) for c in (kh, vh))
+            new_cache = {"k": kh, "v": vh}
         o = o.reshape(B, S, ec.heads_exec, hd)
     else:
         assert cache is not None and layer is not None

@@ -89,8 +89,7 @@ def _ssd_chunked(xh, dt, A, Bh, Ch, chunk, h0=None):
     cs = jnp.cumsum(dA, axis=2)  # inclusive
     # L[l, s] = exp(sum_{k=s+1..l} dA_k) = exp(cs_l - cs_s), l >= s.
     # Mask the *argument*, not the result: exp of the (positive, huge)
-    # upper-triangle differences would overflow to inf and poison the
-    # backward pass via 0*inf.
+    # upper-triangle differences would overflow to inf.
     diff = cs[:, :, :, None, :] - cs[:, :, None, :, :]  # (B,nc,l,s,H)
     tri = jnp.tril(jnp.ones((Q, Q), bool))
     L = jnp.exp(jnp.where(tri[None, None, :, :, None], diff, -1e9))
@@ -131,7 +130,7 @@ def _ssd_chunked(xh, dt, A, Bh, Ch, chunk, h0=None):
 
 def mamba2_apply(
     p, x, *, cfg: ModelConfig, rules, mesh, mode: str, cache: Optional[dict] = None
-) -> Tuple[jnp.ndarray, Optional[dict]]:
+) -> Tuple[jnp.ndarray, dict]:
     m = cfg.mamba
     B, S, _ = x.shape
     d_in = cfg.d_inner
@@ -180,10 +179,8 @@ def mamba2_apply(
         y, h_final = _ssd_chunked(xh, dt, A, Bh, Ch, min(m.chunk, S))
         y = y + p["D"].astype(jnp.float32)[None, None, :, None] * xh.astype(jnp.float32)
         y = y.reshape(B, S, d_in).astype(x.dtype)
-        new_cache = None
-        if mode == "prefill":
-            conv_tail = jnp.concatenate([conv_tail_x, conv_tail_bc], -1)
-            new_cache = {"ssd": h_final.astype(x.dtype), "conv": conv_tail}
+        conv_tail = jnp.concatenate([conv_tail_x, conv_tail_bc], -1)
+        new_cache = {"ssd": h_final.astype(x.dtype), "conv": conv_tail}
 
     y = _gated_rmsnorm(y, z, p["norm"])
     y = shard_constraint(y, ("batch", "seq", "act_inner"), rules, mesh)
@@ -233,8 +230,7 @@ def _sel_scan_fused(u, dt, Bc, Cc, A, h0, chunk):
     The (B,S,C,N)-sized discretized operands dA/dBx are NEVER materialized
     over the full sequence: they are built per chunk inside the scan and
     contracted with C_t immediately, so the live working set is O(B·Q·C·N)
-    per chunk instead of O(B·S·C·N) per layer — the §Perf jamba-train fix
-    (3 full-seq 2.15 GB f32 tensors/layer otherwise).
+    per chunk instead of O(B·S·C·N) per layer.
     """
     B_, S, C = u.shape
     N = Bc.shape[-1]
@@ -252,7 +248,6 @@ def _sel_scan_fused(u, dt, Bc, Cc, A, h0, chunk):
         a2, b2 = e2
         return a1 * a2, a2 * b1 + b2
 
-    @jax.checkpoint  # recompute dA/dBx + scan tree in bwd (25 MB inputs)
     def chunk_step(h, inp):
         uq, dtq, bq, cq = inp  # (B,Q,C), (B,Q,C), (B,Q,N), (B,Q,N)
         dA = jnp.exp(dtq[..., None] * A[None, None])  # (B,Q,C,N)
@@ -269,7 +264,7 @@ def _sel_scan_fused(u, dt, Bc, Cc, A, h0, chunk):
 
 def mamba1_apply(
     p, x, *, cfg: ModelConfig, rules, mesh, mode: str, cache: Optional[dict] = None
-) -> Tuple[jnp.ndarray, Optional[dict]]:
+) -> Tuple[jnp.ndarray, dict]:
     m = cfg.mamba
     B, S, _ = x.shape
     d_in, N = cfg.d_inner, m.d_state
@@ -312,9 +307,7 @@ def mamba1_apply(
         h0 = jnp.zeros((B, d_in, N), jnp.float32)
         y, h_final = _sel_scan_fused(uf, dt, Bc, Cc, A, h0, min(m.chunk, S))
         y = (y + p["D"].astype(jnp.float32) * uf).astype(x.dtype)
-        new_cache = None
-        if mode == "prefill":
-            new_cache = {"h": h_final.astype(x.dtype), "conv": conv_tail}
+        new_cache = {"h": h_final.astype(x.dtype), "conv": conv_tail}
 
     y = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
     out = jnp.einsum("bse,ed->bsd", y, p["w_out"])

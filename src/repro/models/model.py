@@ -1,11 +1,10 @@
 """Model stack builder: dense / MoE / SSM / hybrid decoder assembly.
 
 The layer stack is a ``lax.scan`` over *pattern periods* (HLO size stays
-O(period) even for 88-layer models), with ``jax.checkpoint`` remat around the
-period body in training. The same ``forward`` serves train, prefill and
-decode. Prefill returns a fresh cache per period as the scan's ys; train has
-none. Decode carries the stacked caches through the scan: each layer writes
-its new rows in place and reads its own slice.
+O(period) even for 88-layer models). ``forward`` serves prefill and decode.
+Prefill returns a fresh cache per period as the scan's ys. Decode carries the
+stacked caches through the scan: each layer writes its new rows in place and
+reads its own slice.
 """
 from __future__ import annotations
 
@@ -91,17 +90,19 @@ def forward(
     *,
     rules,
     mesh,
+    mode: str,
     tokens=None,
     embeds=None,
     positions=None,
     cache=None,
-    mode: str = "train",
     block_q: int = 512,
     block_k: int = 512,
-) -> Tuple[jnp.ndarray, Optional[dict], dict]:
-    """Returns (hidden (B,S,D) post-final-norm, new_cache, aux). Only decode
-    reads `cache`: the stacked caches of `init_cache_defs`."""
-    assert mode in ("train", "prefill", "decode")
+) -> Tuple[jnp.ndarray, dict]:
+    """Returns (hidden (B,S,D) post-final-norm, cache). `mode` is "prefill"
+    (a fresh cache of the S tokens) or "decode" (reads and returns `cache`,
+    the stacked caches of `init_cache_defs`)."""
+    if mode not in ("prefill", "decode"):
+        raise ValueError(f"forward: mode {mode!r} is neither 'prefill' nor 'decode'")
     if embeds is None:
         with jax.named_scope("embed"):
             h = jnp.take(params["embed"], tokens, axis=0)
@@ -115,7 +116,7 @@ def forward(
     h = shard_constraint(h, ("res_batch", "seq", "embed"), rules, mesh)
     pattern = cfg.layer_pattern
 
-    def apply_layer(h, aux, lp, lc, t, layer=None):
+    def apply_layer(h, lp, lc, t, layer=None):
         # named scopes ("attention", "mlp") cover each block's norm and residual;
         # in decode `lc` is stacked over periods and `layer` indexes it
         with jax.named_scope("attention" if t.mixer.startswith("attn") else t.mixer):
@@ -154,59 +155,36 @@ def forward(
                 if t.ffn == "dense":
                     y = mlp_apply(lp["ffn"], hn, rules, mesh)
                 else:
-                    y, a = moe_apply(lp["ffn"], hn, cfg, rules, mesh)
-                    aux = {k: aux[k] + a[k] for k in aux}
+                    y = moe_apply(lp["ffn"], hn, cfg, rules, mesh)
                 h = resid + y
-        return h, aux, nc
-
-    # two-level remat for multi-layer periods (jamba's 8-layer block):
-    # the period scan saves only period boundaries; per-layer checkpointing
-    # bounds the recompute working set to ONE layer's intermediates instead
-    # of the whole period's (§Perf, jamba train iteration)
-    if mode == "train" and len(pattern) > 1:
-        apply_layer = jax.checkpoint(apply_layer, static_argnums=(4,))
+        return h, nc
 
     def body(carry, xs):
-        h, aux, stacked = carry  # stacked: decode's caches, else empty
+        h, stacked = carry  # stacked: decode's caches, else empty
         pparams, layer = xs  # layer: decode's index into them
         stacked, new_pcache = dict(stacked), {}
         for i, t in enumerate(pattern):
             key = f"pos{i}"
             if mode == "decode":
-                h, aux, stacked[key] = apply_layer(h, aux, pparams[key], stacked[key], t, layer)
+                h, stacked[key] = apply_layer(h, pparams[key], stacked[key], t, layer)
             else:
-                h, aux, nc = apply_layer(h, aux, pparams[key], None, t)
-                if nc is not None:
-                    new_pcache[key] = nc
-            if mode == "train" and len(pattern) > 1:
-                # barrier between in-period layers: stops the scheduler from
-                # hoisting every layer's remat-recompute ahead of the layer
-                # backwards (which would keep all layers' intermediates live)
-                h, aux = jax.lax.optimization_barrier((h, aux))
-        # residual stream at the period boundary: this is what remat saves
-        # per scan step — sequence-parallel under training rules
-        h = shard_constraint(h, ("res_batch", "seq_res", "embed"), rules, mesh)
-        return (h, aux, stacked), new_pcache
+                h, new_pcache[key] = apply_layer(h, pparams[key], None, t)
+        h = shard_constraint(h, ("res_batch", "seq", "embed"), rules, mesh)
+        return (h, stacked), new_pcache
 
-    if mode == "train":
-        body = jax.checkpoint(body)
-
-    aux0 = {"lb": jnp.zeros((), jnp.float32), "z": jnp.zeros((), jnp.float32)}
     if mode == "decode":
         # the stacked caches ride in the carry, so each layer writes its new
         # rows into the donated buffers in place: as xs/ys, each layer's slice
         # would be copied out and back, and the whole stack once more after
-        carry = (h, aux0, dict(cache))
+        carry = (h, dict(cache))
         layers = jnp.arange(cfg.num_periods, dtype=jnp.int32)
     else:
-        carry, layers = (h, aux0, {}), None
-    (h, aux, stacked), new_cache = jax.lax.scan(body, carry, (params["periods"], layers))
+        carry, layers = (h, {}), None
+    (h, stacked), new_cache = jax.lax.scan(body, carry, (params["periods"], layers))
     if mode == "decode":
         new_cache = stacked
     h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
-    if mode == "train":
-        new_cache = None
-    return h, new_cache, aux
+    return h, new_cache
 
 
 def _head_matrix(params, cfg: ModelConfig):
@@ -222,51 +200,3 @@ def logits_for(params, cfg: ModelConfig, h, rules, mesh):
     logits = softcap(logits, cfg.final_logit_softcap)
     return shard_constraint(logits, ("batch", "seq", "vocab"), rules, mesh)
 
-
-def loss_fn(
-    params,
-    cfg: ModelConfig,
-    ec: ExecConfig,
-    batch: dict,
-    *,
-    rules,
-    mesh,
-    seq_chunk: int = 512,
-    block_q: int = 512,
-    block_k: int = 512,
-):
-    """Chunked cross-entropy train loss (full logits never materialized)."""
-    tokens = batch["tokens"]
-    targets = batch["targets"]
-    mask = batch.get("mask")
-    B, S = tokens.shape
-    if mask is None:
-        mask = jnp.ones((B, S), jnp.float32)
-    embeds = batch.get("embeds")
-    h, _, aux = forward(
-        params, cfg, ec, rules=rules, mesh=mesh, tokens=tokens, embeds=embeds,
-        mode="train", block_q=block_q, block_k=block_k,
-    )
-    w = _head_matrix(params, cfg)
-    ck = min(seq_chunk, S)
-    nc = S // ck
-    h_c = h.reshape(B, nc, ck, -1).transpose(1, 0, 2, 3)
-    t_c = targets.reshape(B, nc, ck).transpose(1, 0, 2)
-    m_c = mask.reshape(B, nc, ck).transpose(1, 0, 2)
-
-    def ce_chunk(tot, xs):
-        hc, tc, mc = xs
-        logits = jnp.einsum("bsd,dv->bsv", hc, w, preferred_element_type=jnp.float32)
-        logits = softcap(logits, cfg.final_logit_softcap)
-        logits = shard_constraint(logits, ("batch", "seq", "vocab"), rules, mesh)
-        lse = jax.nn.logsumexp(logits, -1)
-        tgt = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
-        return tot + ((lse - tgt) * mc).sum(), None
-
-    tot, _ = jax.lax.scan(ce_chunk, jnp.zeros((), jnp.float32), (h_c, t_c, m_c))
-    denom = jnp.maximum(mask.sum(), 1.0)
-    loss = tot / denom
-    if cfg.moe is not None:
-        loss = loss + cfg.moe.router_aux_weight * aux["lb"] / cfg.num_periods
-        loss = loss + cfg.moe.router_z_weight * aux["z"] / cfg.num_periods
-    return loss, {"ce": tot / denom, **aux}

@@ -3,7 +3,7 @@
 Three execution paths:
   * local   — no mesh (CPU smoke tests): sort-based capacity dispatch, all
               experts resident.
-  * sharded — train/prefill under a mesh: tokens are flattened over
+  * sharded — prefill under a mesh: tokens are flattened over
               (data x model) inside a shard_map, dispatched locally
               (sort-based), then moved to their expert shards with an
               all_to_all over the model axis, expert-GEMMed, and moved back.
@@ -75,17 +75,12 @@ def moe_param_defs(cfg: ModelConfig) -> dict:
 
 
 def _route(x2d, router_w, m: MoESpec):
-    """x2d: (T,D) -> (probs (T,K), idx (T,K), aux dict)."""
+    """x2d: (T,D) -> (probs (T,K), idx (T,K))."""
     logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32), router_w.astype(jnp.float32))
     probs_all = jax.nn.softmax(logits, -1)
     top_p, top_i = jax.lax.top_k(probs_all, m.top_k)
     top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
-    # Switch-style load-balancing + router z losses
-    me = probs_all.mean(0)  # (E,)
-    ce = jnp.zeros_like(me).at[top_i.reshape(-1)].add(1.0) / top_i.size
-    lb = m.num_experts * jnp.sum(me * ce)
-    z = jnp.mean(jax.nn.logsumexp(logits, -1) ** 2)
-    return top_p, top_i, {"lb": lb, "z": z}
+    return top_p, top_i
 
 
 def _dispatch_indices(top_i, E: int, C: int):
@@ -124,26 +119,24 @@ def _moe_core(x2d, p, m: MoESpec, C: int):
     """Shared dispatch->ffn->combine on local tokens, all experts local."""
     T, D = x2d.shape
     E = m.num_experts
-    top_p, top_i, aux = _route(x2d, p["router"], m)
+    top_p, top_i = _route(x2d, p["router"], m)
     dest, tok, keep, order = _dispatch_indices(top_i, E, C)
     buf = jnp.zeros((E * C + 1, D), x2d.dtype).at[dest].set(x2d[tok])
     out = _expert_ffn(buf[:-1].reshape(E, C, D), p["w_gate"], p["w_in"], p["w_out"])
     out_rows = out.reshape(E * C, D)
     gathered = jnp.where(keep[:, None], out_rows[jnp.minimum(dest, E * C - 1)], 0.0)
     w = top_p.reshape(-1)[order][:, None].astype(x2d.dtype)
-    y = jnp.zeros((T, D), x2d.dtype).at[tok].add(gathered * w)
-    return y, aux
+    return jnp.zeros((T, D), x2d.dtype).at[tok].add(gathered * w)
 
 
 def moe_apply_local(p, x, cfg: ModelConfig, rules=None, mesh=None):
     m = cfg.moe
     B, S, D = x.shape
     x2d = x.reshape(-1, D)
-    y, aux = _moe_core(x2d, p, m, _capacity(x2d.shape[0], m))
-    y = y.reshape(B, S, D)
+    y = _moe_core(x2d, p, m, _capacity(x2d.shape[0], m)).reshape(B, S, D)
     if m.num_shared_experts:
         y = y + _shared_ffn(p["shared"], x, rules, mesh)
-    return y, aux
+    return y
 
 
 def _shared_ffn(ps, x, rules, mesh):
@@ -153,7 +146,7 @@ def _shared_ffn(ps, x, rules, mesh):
 
 
 # ---------------------------------------------------------------------------
-# Sharded train/prefill path: tokens flattened over (data x model), EP via
+# Sharded prefill path: tokens flattened over (data x model), EP via
 # all_to_all over 'model'.
 # ---------------------------------------------------------------------------
 def moe_apply_sharded(p, x, cfg: ModelConfig, rules, mesh):
@@ -181,7 +174,7 @@ def moe_apply_sharded(p, x, cfg: ModelConfig, rules, mesh):
         Bl, Sl, _ = x_loc.shape
         w_gate, w_in, w_out = _gather_weights(w_gate, w_in, w_out, fsdp_axes)
         x2d = x_loc.reshape(-1, D)
-        top_p, top_i, aux = _route(x2d, router_w, m)
+        top_p, top_i = _route(x2d, router_w, m)
         dest, tok, keep, order = _dispatch_indices(top_i, E, C_loc)
         buf = jnp.zeros((E * C_loc + 1, D), x2d.dtype).at[dest].set(x2d[tok])
         buf = buf[:-1].reshape(E, C_loc, D)
@@ -193,21 +186,20 @@ def moe_apply_sharded(p, x, cfg: ModelConfig, rules, mesh):
         gathered = jnp.where(keep[:, None], out_rows[jnp.minimum(dest, E * C_loc - 1)], 0.0)
         w = top_p.reshape(-1)[order][:, None].astype(x2d.dtype)
         y = jnp.zeros_like(x2d).at[tok].add(gathered * w)
-        aux = {k: jax.lax.pmean(v, ("model",) + batch_axes) for k, v in aux.items()}
-        return y.reshape(Bl, Sl, D), aux
+        return y.reshape(Bl, Sl, D)
 
     xspec = P(batch_axes if len(batch_axes) > 1 else (batch_axes[0] if batch_axes else None), "model", None)
-    y, aux = jax.shard_map(
+    y = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(xspec, P(None, None), wg_spec, wg_spec, wo_spec),
-        out_specs=(xspec, P()),
+        out_specs=xspec,
         check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_in"], p["w_out"])
     y = shard_constraint(y, ("res_batch", "seq", "embed"), rules, mesh)
     if m.num_shared_experts:
         y = y + _shared_ffn(p["shared"], x, rules, mesh)
-    return y, aux
+    return y
 
 
 def _moe_apply_decode(p, x, cfg: ModelConfig, rules, mesh):
@@ -232,7 +224,7 @@ def _moe_apply_decode(p, x, cfg: ModelConfig, rules, mesh):
         Bl, Sl, _ = x_loc.shape
         w_gate, w_in, w_out = _gather_weights(w_gate, w_in, w_out, fsdp_axes)
         x2d = x_loc.reshape(-1, D)
-        top_p, top_i, aux = _route(x2d, router_w, m)
+        top_p, top_i = _route(x2d, router_w, m)
         dest, tok, keep, order = _dispatch_indices(top_i, E, C)
         buf = jnp.zeros((E * C + 1, D), x2d.dtype).at[dest].set(x2d[tok])
         buf = buf[:-1].reshape(E, C, D)
@@ -246,22 +238,21 @@ def _moe_apply_decode(p, x, cfg: ModelConfig, rules, mesh):
         w = top_p.reshape(-1)[order][:, None].astype(x2d.dtype)
         y = jnp.zeros_like(x2d).at[tok].add(gathered * w)
         y = jax.lax.psum(y, "model")
-        aux = {k: jax.lax.pmean(v, ("model",) + batch_axes) for k, v in aux.items()}
-        return y.reshape(Bl, Sl, D), aux
+        return y.reshape(Bl, Sl, D)
 
     bspec = batch_axes if len(batch_axes) > 1 else (batch_axes[0] if batch_axes else None)
     xspec = P(bspec if B % dp == 0 and dp > 1 else None, None, None)
-    y, aux = jax.shard_map(
+    y = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(xspec, P(None, None), wg_spec, wg_spec, wo_spec),
-        out_specs=(xspec, P()),
+        out_specs=xspec,
         check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_in"], p["w_out"])
     y = shard_constraint(y, ("res_batch", "seq", "embed"), rules, mesh)
     if m.num_shared_experts:
         y = y + _shared_ffn(p["shared"], x, rules, mesh)
-    return y, aux
+    return y
 
 
 def moe_apply(p, x, cfg: ModelConfig, rules, mesh):

@@ -10,7 +10,6 @@ counts (vLLM-style), so every assigned arch shards on the 16-wide model axis.
 """
 from __future__ import annotations
 
-import dataclasses
 from dataclasses import dataclass
 from typing import Mapping, Optional, Sequence, Tuple, Union
 
@@ -35,22 +34,14 @@ class ShardingRules:
             raise KeyError(f"unknown logical axis {logical!r}")
         return self.table[logical]
 
-    def override(self, **kw: MeshAxes) -> "ShardingRules":
-        t = dict(self.table)
-        t.update(kw)
-        return ShardingRules(t)
-
 
 DEFAULT_RULES = ShardingRules(
     {
         # activations
         "batch": ("pod", "data"),
-        # residual-stream batch: usually follows "batch", but weight-
-        # stationary 2D decode replicates it so the contraction dim can
-        # shard over data instead (EXPERIMENTS.md §Perf)
+        # residual-stream batch: resolves as "batch" does under these rules
         "res_batch": ("pod", "data"),
         "seq": None,
-        "seq_res": None,  # residual stream at layer boundaries; "model" = SP
         "kv_seq": None,  # set to "data" for context-parallel long decode
         "embed": None,
         "act_heads": "model",
@@ -70,7 +61,6 @@ DEFAULT_RULES = ShardingRules(
         "state": None,
         "conv": None,
         "periods": None,
-        "zero": "data",  # extra axis for ZeRO-sharded optimizer state
     }
 )
 
@@ -171,74 +161,3 @@ def make_exec_config(cfg: ModelConfig, tp: int) -> ExecConfig:
     if h % kv != 0:
         h = ceil_to(h, kv)
     return ExecConfig(cfg, tp, h, kv)
-
-
-# ---------------------------------------------------------------------------
-# Rule presets per (arch, shape-kind): how each cell is distributed
-# ---------------------------------------------------------------------------
-def rules_for(cfg: ModelConfig, shape_kind: str, seq_len: int = 0,
-              batch: int = 0) -> ShardingRules:
-    """Distribution strategy per cell (DESIGN.md §4):
-
-      * dense weights FSDP over data (embed -> data) when the TP-16 shard
-        would not fit 16 GB HBM (mistral-large, and all train cells — ZeRO-3
-        posture for training);
-      * expert-weight FSDP (expert_embed -> data) when per-chip expert
-        shards are too large (dbrx);
-      * long_500k decode: batch=1 -> batch unsharded, KV sequence sharded
-        over (pod, data) = context-parallel split-KV decode.
-    """
-    rules = DEFAULT_RULES
-    dtype_bytes = 2
-    tp_shard_gb = cfg.param_count() * dtype_bytes / 16 / 1e9
-    if shape_kind == "train" or tp_shard_gb > 8.0:
-        rules = rules.override(embed=("data",))
-        if shape_kind == "decode" and batch > 1:
-            # weight-stationary 2D decode: replicate the (tiny) residual
-            # activations over data so the embed contraction shards over
-            # data — O(activation) collectives instead of O(weight) gathers
-            # per token (§Perf, mistral-large decode: 1.84x)
-            rules = rules.override(res_batch=None)
-    if shape_kind == "train" and seq_len % 16 == 0:
-        # Megatron-style sequence parallelism on the residual stream: the
-        # remat-saved per-layer carries shard over the model axis (XLA
-        # inserts the all-gather/reduce-scatter pairs at layer boundaries)
-        rules = rules.override(seq_res="model")
-    if cfg.moe is not None:
-        e = cfg.moe
-        n_moe_layers = (
-            sum(1 for t in cfg.layer_pattern if t.ffn == "moe") * cfg.num_periods
-        )
-        expert_params = (
-            n_moe_layers * (e.num_experts + e.num_shared_experts)
-            * 3 * cfg.d_model * e.d_ff_expert
-        )
-        # expert-weight FSDP only when the per-chip expert shard cannot fit —
-        # serving pays the gather per decode step, so avoid it when possible
-        # (EXPERIMENTS.md §Perf, jamba decode iteration)
-        if expert_params * dtype_bytes / 16 > 8e9 or shape_kind == "train":
-            rules = rules.override(expert_embed="data")
-    if shape_kind == "decode" and batch == 1:
-        rules = rules.override(batch=None, kv_seq=("pod", "data"))
-    return rules
-
-
-def validate_divisibility(cfg: ModelConfig, tp: int) -> None:
-    """Every TP-sharded dimension must divide by tp (post exec-expansion)."""
-    ec = make_exec_config(cfg, tp)
-    checks = {"vocab_padded": cfg.vocab_padded, "d_model": cfg.d_model}
-    if cfg.family != "ssm":
-        checks["heads_exec"] = ec.heads_exec
-        checks["kv_exec"] = ec.kv_exec
-    if cfg.d_ff:
-        checks["d_ff"] = cfg.d_ff
-    if cfg.moe:
-        checks["experts"] = cfg.moe.num_experts
-    if cfg.mamba:
-        nheads = (
-            cfg.d_inner // cfg.mamba.head_dim if cfg.mamba.version == 2 else cfg.d_inner
-        )
-        checks["mamba_heads"] = nheads
-    for name, dim in checks.items():
-        if dim % tp != 0:
-            raise ValueError(f"{cfg.name}: {name}={dim} not divisible by tp={tp}")

@@ -81,7 +81,7 @@ def profile_engine(engine, batches: Sequence[int] = (1, 4), ctxs: Sequence[int] 
 
     table = ProfileTable()
     for tp in engine.tps:
-        engine._switch_mesh_only(tp)
+        engine.switch_tp(tp)
         for b in batches:
             if b > engine.econf.n_slots:
                 continue
@@ -99,7 +99,7 @@ def profile_engine(engine, batches: Sequence[int] = (1, 4), ctxs: Sequence[int] 
                 table.decode_s[(tp, b, ctx)] = dt
         for L in engine.econf.prefill_buckets:
             toks = np.zeros((1, L), np.int32)
-            dt = time_fn(lambda: engine._prefill_fns[(tp, L)](engine.storage, toks, L)[0])
+            dt = time_fn(lambda: engine._prefill_fns[tp](engine.storage, toks, L)[0])
             table.prefill_s[(tp, L)] = dt
     return table
 

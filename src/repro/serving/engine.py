@@ -15,14 +15,14 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core.migration import cache_shardings, migrate_cache, reshard
+from repro.core.migration import MigrationAborted, cache_shardings, migrate_cache
 from repro.core.weight_store import WeightStore, make_exec_mesh
 from repro.models import forward, init_cache_defs, model_param_defs
 from repro.models.model import logits_for
@@ -73,7 +73,7 @@ def make_decode_step(cfg: ModelConfig, store: WeightStore, tp: int, mesh,
 
     def decode_step(storage, caches, tokens, positions):
         params = sel(storage)
-        h, new_caches, _ = forward(
+        h, new_caches = forward(
             params, cfg, cache_ec, rules=rules, mesh=mesh, tokens=tokens,
             positions=positions, cache=caches, mode="decode",
         )
@@ -93,7 +93,7 @@ def make_prefill_step(cfg: ModelConfig, store: WeightStore, tp: int, mesh,
 
     def prefill_step(storage, tokens, true_len):
         params = sel(storage)
-        h, cache, _ = forward(
+        h, cache = forward(
             params, cfg, cache_ec, rules=rules, mesh=mesh, tokens=tokens,
             mode="prefill", block_q=64, block_k=64,
         )
@@ -181,7 +181,7 @@ class ServingEngine:
         dummy_pos = np.zeros((self.econf.n_slots,), np.int32)
         cur = self.tp
         for tp in self.tps:
-            self._switch_mesh_only(tp)
+            self._rebind_and_migrate(tp)
             nxt, _, self.slots.arrays = self._decode_fns[tp](
                 self.storage, self.slots.arrays, dummy_tok, dummy_pos
             )
@@ -193,39 +193,53 @@ class ServingEngine:
                 # slot 0 holds junk until its first admit overwrites it
                 self.slots.arrays = self._insert_fn(self.slots.arrays, seq_cache, 0)
                 jax.block_until_ready(self.slots.arrays)
-        self._switch_mesh_only(cur)
+        self._rebind_and_migrate(cur)
         return time.perf_counter() - t0
 
-    def _switch_mesh_only(self, tp: int) -> None:
+    def _rebind_and_migrate(self, tp: int) -> Tuple[float, float]:
+        """Move the engine to TP `tp`: rebind the weights to its mesh
+        (zero-copy), then migrate the slot caches to its layout. Returns
+        (rebind_s, migrate_s).
+
+        Transactional: on any failure the previous weight binding is put back
+        and `MigrationAborted` raised, with `tp` and the caches as they were.
+        Rollback is free, since the rebind aliases the same per-device
+        buffers and migration leaves its source intact."""
         if tp == self.tp:
-            return
-        self.storage = self.store.rebind(self.storage, self.meshes[tp])
-        self.slots.arrays = reshard(self.slots.arrays, self._cache_shardings(tp))
-        self.tp = tp
+            return 0.0, 0.0
+        prev = self.storage
+        try:
+            t0 = time.perf_counter()
+            with _span("engine.rebind"):
+                self.storage = self.store.rebind(self.storage, self.meshes[tp])
+            rebind_s = time.perf_counter() - t0
+            with _span("engine.migrate"):
+                arrays, migrate_s = migrate_cache(self.slots.arrays, self._cache_shardings(tp))
+        except Exception as e:  # noqa: BLE001 - any failure leaves the old TP in place
+            self.storage = prev
+            if isinstance(e, MigrationAborted):
+                raise
+            raise MigrationAborted(f"switch TP {self.tp}->{tp} aborted: {e}") from e
+        self.slots.arrays, self.tp = arrays, tp
+        return rebind_s, migrate_s
 
     def switch_tp(self, tp: int) -> dict:
         """Stop-and-migrate TP switch (paper §3.2): zero-copy weight rebind +
-        one resharding program for all slot caches."""
+        one resharding program for all slot caches. Raises `MigrationAborted`
+        with the engine still serving at its old TP."""
         if tp not in self.meshes:
             raise ValueError(
                 f"switch_tp({tp}): no mesh for TP {tp}; this engine has TPs {self.tps}"
             )
         if tp == self.tp:
             return {"rebind_s": 0.0, "migrate_s": 0.0}
-        with _span("engine.switch", from_tp=self.tp, to_tp=tp):
-            t0 = time.perf_counter()
-            with _span("engine.rebind"):
-                self.storage = self.store.rebind(self.storage, self.meshes[tp])
-            rebind_s = time.perf_counter() - t0
-            with _span("engine.migrate"):
-                self.slots.arrays, migrate_s = migrate_cache(
-                    self.slots.arrays, self._cache_shardings(tp)
-                )
+        from_tp = self.tp
+        with _span("engine.switch", from_tp=from_tp, to_tp=tp):
+            rebind_s, migrate_s = self._rebind_and_migrate(tp)
         self.stats.switch_log.append({
-            "step": self.stats.steps, "from_tp": self.tp, "to_tp": tp,
+            "step": self.stats.steps, "from_tp": from_tp, "to_tp": tp,
             "rebind_s": rebind_s, "migrate_s": migrate_s,
         })
-        self.tp = tp
         return {"rebind_s": rebind_s, "migrate_s": migrate_s}
 
     # ------------------------------------------------------------------
@@ -240,12 +254,12 @@ class ServingEngine:
             return self._admit(req)
 
     def _admit(self, req: Request) -> bool:
+        L = self._bucket(req.prompt_len)  # before alloc: a refused prompt holds no slot
         slot = self.slots.alloc()
         if slot is None:
             return False
         if req.arrival_s == 0.0:  # demo requests: arrival = admission
             req.arrival_s = time.perf_counter()
-        L = self._bucket(req.prompt_len)
         tokens = np.zeros((1, L), np.int32)
         tokens[0, : req.prompt_len] = req.prompt
         with _span("engine.prefill"):

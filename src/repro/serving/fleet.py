@@ -2,9 +2,8 @@
 serving cells behind one admission tier, advancing under one fleet clock.
 
 A *cell* is a full :class:`~repro.serving.simulator.Simulator` — its own
-policy, planner, groups, and KV accounting over a 16–512-chip pool (the
-dry-run cell builders in ``launch/cells.py`` model the same unit at the
-array level). The fleet:
+policy, planner, groups, and KV accounting over a 16–512-chip pool. The
+fleet:
 
 * owns the merged arrival stream and assigns each arrival to a cell at
   admission (seeded, deterministic, least-admitted-share first);

@@ -1,10 +1,10 @@
 """Calibrated event-driven serving simulator.
 
 Replays 10-minute traces at full cluster scale against the analytic profile
-model (profiles/perf_model.py, same constants as the dry-run roofline). This
-is what produces the paper's evaluation figures: every baseline the paper
-compares against is a `Policy` here, and Nitsum itself is the planner +
-global/local schedulers + ms-level switch mechanisms.
+model (profiles/perf_model.py). This is what produces the paper's evaluation
+figures: every baseline the paper compares against is a `Policy` here, and
+Nitsum itself is the planner + global/local schedulers + ms-level switch
+mechanisms.
 
 Execution model per group (one TP group of `tp` chips):
   * prefill runs serially (FCFS) and, in mixed groups, preempts decode —

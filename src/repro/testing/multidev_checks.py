@@ -11,10 +11,13 @@ Checks:
       rebinds buffers zero-copy (pointer-identical shards).
   moe_sharded  — shard_map EP MoE == local oracle.
   migration    — KV cache resharding across TP meshes preserves contents.
-  fault_abort  — mid-flight aborts (docs/faults.md): a switch interrupted
-      by a fault rolls back transactionally, a migration whose source dies
-      leaves the original cache intact, and a weight reload on a shrunken
-      pool (WeightStore.shrink) still serves correct logits.
+  fault_abort  — mid-flight aborts (docs/faults.md): a migration whose
+      target is invalid leaves the original cache intact, and a weight
+      reload on a shrunken pool (WeightStore.shrink) still serves correct
+      logits.
+  engine       — greedy trajectories are unchanged by mid-stream TP switches.
+  engine_rollback — a TP switch whose migration fails leaves the engine at
+      its old TP, serving the same tokens, and a retry succeeds.
 """
 import os
 import sys
@@ -79,7 +82,7 @@ def check_weight_store() -> None:
 
         def step(storage, tokens):
             params = sel(storage)
-            h, _, _ = forward(
+            h, _ = forward(
                 params, cfg, ec, rules=RULES, mesh=mesh, tokens=tokens,
                 mode="prefill", block_q=16, block_k=16,
             )
@@ -130,8 +133,8 @@ def check_weight_store() -> None:
 
     def step2(storage, tokens):
         params = sel(storage)
-        h, _, _ = forward(params, cfg, ec, rules=RULES, mesh=mesh_to,
-                          tokens=tokens, mode="prefill", block_q=16, block_k=16)
+        h, _ = forward(params, cfg, ec, rules=RULES, mesh=mesh_to,
+                       tokens=tokens, mode="prefill", block_q=16, block_k=16)
         return logits_for(params, cfg, h, RULES, mesh_to)
 
     tok_sh = jax.device_put(tokens, NamedSharding(mesh_to, P("data", None)))
@@ -150,18 +153,11 @@ def check_moe_sharded() -> None:
     params = init_params(defs, jax.random.PRNGKey(0), jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model), jnp.float32)
 
-    y_local, aux_local = moe_apply_local(params, x, cfg)
+    y_local = moe_apply_local(params, x, cfg)
     with mesh:  # Mesh-as-contextmanager works on old and new jax alike
-        y_sh, aux_sh = jax.jit(
-            lambda p, x: moe_apply_sharded(p, x, cfg, RULES, mesh)
-        )(params, x)
+        y_sh = jax.jit(lambda p, x: moe_apply_sharded(p, x, cfg, RULES, mesh))(params, x)
     np.testing.assert_allclose(
         np.asarray(y_sh), np.asarray(y_local), rtol=5e-4, atol=5e-4
-    )
-    # per-shard LB loss is an average of local estimates (standard practice);
-    # it approximates but does not equal the global statistic
-    np.testing.assert_allclose(
-        float(aux_sh["lb"]), float(aux_local["lb"]), rtol=5e-2
     )
     print("moe_sharded: matches local oracle")
 
@@ -226,7 +222,6 @@ def check_migration() -> None:
 
 def check_fault_abort() -> None:
     from repro.core.migration import MigrationAborted
-    from repro.core.tp_switch import SwitchAborted, TPSwitchController
 
     cfg = _tiny_cfg()
     devices = jax.devices()
@@ -242,9 +237,9 @@ def check_fault_abort() -> None:
 
         def step(storage, tokens):
             params = sel(storage)
-            h, _, _ = forward(params, cfg, ec, rules=RULES, mesh=mesh,
-                              tokens=tokens, mode="prefill",
-                              block_q=16, block_k=16)
+            h, _ = forward(params, cfg, ec, rules=RULES, mesh=mesh,
+                           tokens=tokens, mode="prefill",
+                           block_q=16, block_k=16)
             return logits_for(params, cfg, h, RULES, mesh)
 
         tok_sh = jax.device_put(tokens, NamedSharding(mesh, P("data", None)))
@@ -254,31 +249,7 @@ def check_fault_abort() -> None:
     ref = serve(store, store.build(canonical, make_exec_mesh(devices, 1)),
                 1, make_exec_mesh(devices, 1))
 
-    # 1. switch interrupted by a fault: transactional rollback
-    ctl = TPSwitchController(store, devices, (1, 2, 4))
-    ctl.install(canonical, 1)
-    storage_before = ctl.storage
-
-    def dying_migrate(mesh):
-        raise RuntimeError("device lost mid-migration")
-
-    try:
-        ctl.switch(2, migrate_fn=dying_migrate)
-        raise AssertionError("switch did not abort")
-    except SwitchAborted:
-        pass
-    assert ctl.current_tp == 1 and ctl.storage is storage_before
-    assert ctl.stats.n_aborts == 1 and ctl.stats.n_switches == 0
-    # serving at the rolled-back TP still matches the reference
-    np.testing.assert_allclose(
-        serve(store, ctl.storage, 1, ctl.meshes[1]), ref,
-        rtol=2e-4, atol=2e-4,
-    )
-    ctl.switch(2)  # retry after the fault clears
-    assert ctl.current_tp == 2 and ctl.stats.n_switches == 1
-    print("fault_abort: interrupted switch rolled back, retry succeeded")
-
-    # 2. migration whose target is invalid: original cache untouched
+    # 1. migration whose target is invalid: original cache untouched
     ec_lo = make_exec_config(cfg, 1)
     cache_defs = init_cache_defs(cfg, ec_lo, B, 32)
     cache = init_params(cache_defs, jax.random.PRNGKey(2), jnp.float32)
@@ -299,7 +270,7 @@ def check_fault_abort() -> None:
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     print("fault_abort: aborted migration left the source cache intact")
 
-    # 3. weight reload on a shrunken pool (lost one 4-chip host)
+    # 2. weight reload on a shrunken pool (lost one 4-chip host)
     survivors = devices[: len(devices) // 2]
     small = store.shrink(survivors)
     assert small.N == len(survivors) and small.bytes_per_device() > 0
@@ -312,10 +283,9 @@ def check_fault_abort() -> None:
           "identical logits")
 
 
-def check_engine() -> None:
-    """End-to-end: serving with mid-stream TP switches must produce the same
-    greedy trajectories as a fixed-TP run (the switch is semantically
-    invisible — the paper's correctness requirement for §3.2)."""
+def _tiny_engine():
+    """(a maker of float32 engines over every device at TP 1, 2 and 4, a
+    maker of the same ten seeded requests)."""
     from repro.serving.engine import EngineConfig, ServingEngine
     from repro.serving.request import Request
 
@@ -338,13 +308,21 @@ def check_engine() -> None:
             for i in range(10)
         ]
 
-    eng_a = ServingEngine(cfg, params, jax.devices(), econf)
+    return lambda: ServingEngine(cfg, params, jax.devices(), econf), mk_requests
+
+
+def check_engine() -> None:
+    """End-to-end: serving with mid-stream TP switches must produce the same
+    greedy trajectories as a fixed-TP run (the switch is semantically
+    invisible — the paper's correctness requirement for §3.2)."""
+    mk_engine, mk_requests = _tiny_engine()
+    eng_a = mk_engine()
     warm = eng_a.warmup()
     print(f"engine: warmed {len(eng_a.tps)} TP levels in {warm:.1f}s (offline)")
     done_a = eng_a.run(mk_requests())
     base = {r.req_id: list(r.generated) for r in done_a}
 
-    eng_b = ServingEngine(cfg, params, jax.devices(), econf)
+    eng_b = mk_engine()
     eng_b.warmup()
     done_b = eng_b.run(mk_requests(), switch_schedule={3: 2, 7: 4, 13: 1, 19: 2})
     assert len(eng_b.stats.switch_log) >= 3
@@ -362,53 +340,54 @@ def check_engine() -> None:
     )
 
 
-def check_train_step() -> None:
-    """Sharded (data x model) train step == single-device train step, with
-    ZeRO-1 sharded optimizer state and f32 numerics."""
-    from repro.configs import get_config, reduced
-    from repro.training.data import SyntheticDataset
-    from repro.training.optimizer import AdamWConfig
-    from repro.training.train_step import TrainStepConfig, init_opt_state, make_train_step
+def check_engine_rollback() -> None:
+    """A TP switch whose cache migration fails raises MigrationAborted and
+    leaves the engine as it was: the same TP, storage, caches and switch
+    log. Serving then goes on with the tokens of a run that never tried to
+    switch, and a retry of the switch succeeds."""
+    import repro.serving.engine as engine_mod
+    from repro.core.migration import MigrationAborted
 
-    cfg = reduced(get_config("h2o-danube-1.8b"))
-    ec1 = make_exec_config(cfg, 1)
-    defs = model_param_defs(cfg, ec1)
-    params0 = init_params(defs, jax.random.PRNGKey(0), jnp.float32)
-    tcfg = TrainStepConfig(opt=AdamWConfig(lr=1e-3), seq_chunk=16, block_q=16, block_k=16)
-    ds = SyntheticDataset(cfg, batch=4, seq=32)
+    mk_engine, mk_requests = _tiny_engine()
+    base = {r.req_id: list(r.generated) for r in mk_engine().run(mk_requests())}
 
-    # reference: single device
-    step1, _ = make_train_step(cfg, ec1, RULES, None, tcfg)
-    p = jax.tree_util.tree_map(jnp.copy, params0)
-    o = init_opt_state(p, tcfg)
-    losses_ref = []
-    for i in range(5):
-        p, o, m = step1(p, o, ds.at(i))
-        losses_ref.append(float(m["loss"]))
-    ref_params = p
+    eng = mk_engine()
+    eng.warmup()
+    real = engine_mod.migrate_cache
 
-    # sharded: (data=2, model=2) with ZeRO-1 opt state
-    devices = jax.devices()[:4]
-    mesh = Mesh(np.array(devices).reshape(2, 2), ("data", "model"))
-    ec = make_exec_config(cfg, 2)
-    # exec kv == canonical (kv=2 >= tp=2) so params carry over directly
-    stepN, sh = make_train_step(cfg, ec, RULES, mesh, tcfg)
-    p = jax.device_put(params0, sh["params"])
-    o = init_opt_state(params0, tcfg)
-    o = jax.tree_util.tree_map(jax.device_put, o, dict(sh["opt_state"]))
-    losses_sh = []
-    for i in range(5):
-        p, o, m = stepN(p, o, ds.at(i))
-        losses_sh.append(float(m["loss"]))
-    for a, b in zip(losses_ref, losses_sh):
-        assert abs(a - b) / abs(a) < 2e-4, (losses_ref, losses_sh)
-    for a, b in zip(
-        jax.tree_util.tree_leaves(ref_params), jax.tree_util.tree_leaves(p)
-    ):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=5e-3, atol=5e-4
-        )
-    print(f"train_step: sharded==single-device over 5 steps (losses {losses_sh})")
+    def dying_migrate(cache, target):  # fails once, as a device lost mid-migration
+        engine_mod.migrate_cache = real
+        raise RuntimeError("device lost mid-migration")
+
+    pending, done, step = mk_requests(), [], 0
+    while pending or any(r is not None for r in eng.slot_req):
+        if step == 3:
+            before = (eng.tp, eng.storage, eng.slots.arrays, list(eng.stats.switch_log))
+            engine_mod.migrate_cache = dying_migrate
+            try:
+                eng.switch_tp(2)
+                raise AssertionError("switch_tp(2) did not abort")
+            except MigrationAborted:
+                pass
+            finally:
+                engine_mod.migrate_cache = real
+            after = (eng.tp, eng.storage, eng.slots.arrays, eng.stats.switch_log)
+            assert after[0] == before[0] == 1, after[0]
+            assert after[1] is before[1] and after[2] is before[2], "storage or caches rebound"
+            assert after[3] == before[3] == [], after[3]
+        while pending and eng.slots.free:
+            eng.admit(pending.pop(0))
+        done.extend(eng.step())
+        step += 1
+    assert {r.req_id: list(r.generated) for r in done} == base, "tokens changed after the abort"
+    print(f"engine_rollback: aborted switch TP 1->2 rolled back; {len(done)} requests "
+          "served the tokens of a run that never switched")
+
+    eng.switch_tp(2)  # retry once the fault has cleared
+    assert eng.tp == 2 and [(s["from_tp"], s["to_tp"]) for s in eng.stats.switch_log] == [(1, 2)]
+    again = {r.req_id: list(r.generated) for r in eng.run(mk_requests())}
+    assert again == base, "tokens changed after the retried switch"
+    print("engine_rollback: retry succeeded; TP 2 serves the same tokens")
 
 
 CHECKS = {
@@ -417,7 +396,7 @@ CHECKS = {
     "migration": check_migration,
     "fault_abort": check_fault_abort,
     "engine": check_engine,
-    "train_step": check_train_step,
+    "engine_rollback": check_engine_rollback,
 }
 
 

@@ -157,6 +157,19 @@ def test_switch_tp_rejects_a_tp_without_mesh():
     assert eng.switch_tp(1) == {"rebind_s": 0.0, "migrate_s": 0.0}
 
 
+def test_admit_refuses_a_prompt_longer_than_the_largest_bucket():
+    from repro.serving.request import Request
+
+    cfg = small_cfg()
+    eng = build_engine(cfg, jax.devices()[:1], SMALL_ECONF, seed=0)
+    longest = max(SMALL_ECONF.prefill_buckets)
+    req = Request(0, "strict", np.zeros(longest + 1, np.int32), 4)
+    with pytest.raises(ValueError, match=f"prompt length {longest + 1} exceeds buckets"):
+        eng.admit(req)
+    assert len(eng.slots.free) == SMALL_ECONF.n_slots  # the refused prompt holds no slot
+    assert eng.slot_req == [None] * SMALL_ECONF.n_slots
+
+
 @pytest.mark.parametrize("env_dir", [False, True])
 def test_compile_cache_goes_to_one_directory(tmp_path, env_dir):
     """Entries land in $JAX_COMPILATION_CACHE_DIR when it is set, else in

@@ -38,11 +38,10 @@ def test_kv_migration_preserves_contents():
 
 
 def test_fault_aborts_are_transactional():
-    """Mid-flight abort paths (docs/faults.md): interrupted switch rolls
-    back, dying migration leaves the source intact, reload on a shrunken
-    pool serves correct logits."""
+    """Mid-flight abort paths (docs/faults.md): a failed migration leaves the
+    source intact, reload on a shrunken pool serves correct logits. The
+    engine's own rollback is test_engine_switch_rolls_back_when_migration_fails."""
     out = _run("fault_abort")
-    assert "rolled back" in out
     assert "source cache intact" in out
     assert "shrunken pool" in out
 
@@ -52,5 +51,7 @@ def test_engine_serves_with_tp_switches():
     assert "switch" in out
 
 
-def test_sharded_train_step_matches_single_device():
-    _run("train_step", 4)
+def test_engine_switch_rolls_back_when_migration_fails():
+    out = _run("engine_rollback")
+    assert "aborted switch TP 1->2 rolled back" in out
+    assert "retry succeeded" in out
