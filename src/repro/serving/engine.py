@@ -10,6 +10,21 @@ mesh is (data=N/t, model=t) — the data axis is the paper's "N/t independent
 TP groups", executing in lockstep with per-group batches composed by the
 scheduler. Greedy decoding keeps trajectories deterministic so integration
 tests can assert that a mid-stream TP switch is semantically invisible.
+
+The decode loop runs one step ahead of the host. A decode step returns its
+next tokens on the device, in the shape and sharding it takes them, and they
+are the next step's input without a trip through the host. `step` dispatches
+step n+1 first, then reads step n's tokens to the host: while the host reads
+and appends them, the device runs step n+1. The host owns the positions,
+which advance at dispatch, and knows from them and each request's
+`max_new_tokens` which step is a request's last; it releases the slot in the
+call that dispatches that step, so `admit` may refill it at once (the insert
+is ordered after the step in flight by its data dependency on the donated
+caches). A request is returned by the call that reads its last token. A
+read must come before the next dispatch (a drain) when `admit` puts a new
+first token into the batch, when `switch_tp` moves to another mesh, and when
+nothing is left to dispatch; the host's token vector is then whole, and the
+next dispatch uploads it.
 """
 from __future__ import annotations
 
@@ -26,14 +41,20 @@ from repro.core.migration import MigrationAborted, cache_shardings, migrate_cach
 from repro.core.weight_store import WeightStore, make_exec_mesh
 from repro.models import forward, init_cache_defs, model_param_defs
 from repro.models.model import logits_for
-from repro.parallel.sharding import DEFAULT_RULES, make_exec_config
+from repro.parallel.sharding import (
+    DEFAULT_RULES, make_exec_config, shard_constraint, sharding_for,
+)
 from repro.serving.kv_cache import SlotCache
 from repro.serving.request import Request, RequestState
 
 # Host spans the engine opens (jax.profiler.TraceAnnotation, and
 # StepTraceAnnotation for "engine.step"): they land in the profiler's trace on
 # the device planes' clock. Identifiers (req_id, step_num, from_tp, to_tp) go
-# in as trace stats, so the names stay fixed.
+# in as trace stats, so the names stay fixed. Besides these, "engine.drain"
+# wraps a read of the step in flight that must come before the next dispatch
+# (an admit into a running batch, a TP switch, the end of the work); it is
+# left out of SPANS, which the benchmark's recorded engine traces are checked
+# against, and a traced run labels the device's idle time inside it.
 SPANS = (
     "engine.admit", "engine.prefill", "engine.insert", "engine.first_token",
     "engine.step", "engine.decode", "engine.sync", "engine.logits", "engine.slots",
@@ -45,6 +66,7 @@ SPANS = (
 # and argmax, here) and "migrate" (core/migration.migrate).
 SCOPES = ("select", "embed", "attention", "kv_write", "mlp", "head", "migrate")
 _span = jax.profiler.TraceAnnotation
+TOKEN_AXES = ("batch",)  # a decode step's token input and output, (B, 1)
 
 
 @dataclass
@@ -59,7 +81,9 @@ class EngineConfig:
 
 @dataclass
 class StepStats:
-    steps: int = 0
+    steps: int = 0  # decode steps dispatched
+    overlapped: int = 0  # steps dispatched while the previous step's tokens were unread
+    drains: int = 0  # reads of the step in flight forced before a dispatch
     # one entry per executed switch: step, from_tp, to_tp, rebind_s, migrate_s
     switch_log: List[dict] = field(default_factory=list)
 
@@ -67,8 +91,10 @@ class StepStats:
 def make_decode_step(cfg: ModelConfig, store: WeightStore, tp: int, mesh,
                      cache_ec, rules=DEFAULT_RULES):
     """Jitted decode step at TP `tp` on `mesh`:
-    (storage, caches, tokens (B,1), positions (B,)) -> (next, logits, caches).
-    The caches are donated; their layout is fixed by `cache_ec`."""
+    (storage, caches, tokens (B,1), positions (B,)) -> (next (B,1), logits, caches).
+    `next` is held to the sharding of TOKEN_AXES, which the engine gives
+    `tokens`, so a step's output is the next step's input to the same
+    executable. The caches are donated; their layout is fixed by `cache_ec`."""
     sel = store.select_fn(tp, mesh)
 
     def decode_step(storage, caches, tokens, positions):
@@ -79,7 +105,8 @@ def make_decode_step(cfg: ModelConfig, store: WeightStore, tp: int, mesh,
         )
         with jax.named_scope("head"):
             logits = logits_for(params, cfg, h, rules, mesh)[:, 0, : cfg.vocab_size]
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+            nxt = shard_constraint(nxt, TOKEN_AXES, rules, mesh)
         return nxt, logits, new_caches
 
     return jax.jit(decode_step, donate_argnums=(1,))
@@ -140,8 +167,15 @@ class ServingEngine:
             cfg, cache_ec, econf.n_slots, econf.max_len, econf.dtype,
             cache_shardings(cache_defs, rules, self.meshes[self.tp]),
         )
+        # slot -> the request whose next step is still to dispatch
         self.slot_req: List[Optional[Request]] = [None] * econf.n_slots
+        # the next step's tokens on the host, whole after every read of the
+        # step in flight; `_tokens` is the device copy, None until uploaded
         self.next_tokens = np.zeros(econf.n_slots, np.int32)
+        self._tokens = None
+        # the step dispatched and not yet read: (next, logits or None, [(slot, req, last)])
+        self._inflight: Optional[tuple] = None
+        self._finished: List[Request] = []  # read to the end, not yet returned
         self.stats = StepStats()
         self.logit_trace: Dict[int, list] = {}
 
@@ -175,17 +209,20 @@ class ServingEngine:
     def warmup(self) -> float:
         """AOT-warm every (tp, stage) executable, and the cache resharding
         between consecutive candidate TPs — the paper's offline CUDA-graph
-        capture. Returns total compile seconds."""
+        capture. Each decode step is called as the loop calls it: from an
+        uploaded token vector, then from a step's own output. Returns total
+        compile seconds."""
         t0 = time.perf_counter()
-        dummy_tok = np.zeros((self.econf.n_slots, 1), np.int32)
         dummy_pos = np.zeros((self.econf.n_slots,), np.int32)
         cur = self.tp
         for tp in self.tps:
             self._rebind_and_migrate(tp)
-            nxt, _, self.slots.arrays = self._decode_fns[tp](
-                self.storage, self.slots.arrays, dummy_tok, dummy_pos
-            )
-            jax.block_until_ready(nxt)
+            tokens = self._device_tokens()
+            for _ in range(2):
+                tokens, _, self.slots.arrays = self._decode_fns[tp](
+                    self.storage, self.slots.arrays, tokens, dummy_pos
+                )
+            jax.block_until_ready(tokens)
             for L in self.econf.prefill_buckets:
                 _, _, seq_cache = self._prefill_fns[tp](
                     self.storage, np.zeros((1, L), np.int32), 1
@@ -194,7 +231,18 @@ class ServingEngine:
                 self.slots.arrays = self._insert_fn(self.slots.arrays, seq_cache, 0)
                 jax.block_until_ready(self.slots.arrays)
         self._rebind_and_migrate(cur)
+        self._tokens = None
         return time.perf_counter() - t0
+
+    def _device_tokens(self):
+        """The next step's tokens on the device: the last step's output, or
+        the host's vector uploaded with the sharding that output has."""
+        if self._tokens is None:
+            self._tokens = jax.device_put(
+                self.next_tokens.reshape(-1, 1),
+                sharding_for(TOKEN_AXES, self.rules, self.meshes[self.tp]),
+            )
+        return self._tokens
 
     def _rebind_and_migrate(self, tp: int) -> Tuple[float, float]:
         """Move the engine to TP `tp`: rebind the weights to its mesh
@@ -207,6 +255,8 @@ class ServingEngine:
         buffers and migration leaves its source intact."""
         if tp == self.tp:
             return 0.0, 0.0
+        self._drain()
+        self._tokens = None  # the next dispatch uploads them on the new mesh
         prev = self.storage
         try:
             t0 = time.perf_counter()
@@ -224,9 +274,10 @@ class ServingEngine:
         return rebind_s, migrate_s
 
     def switch_tp(self, tp: int) -> dict:
-        """Stop-and-migrate TP switch (paper §3.2): zero-copy weight rebind +
-        one resharding program for all slot caches. Raises `MigrationAborted`
-        with the engine still serving at its old TP."""
+        """Stop-and-migrate TP switch (paper §3.2): a read of the step in
+        flight, zero-copy weight rebind + one resharding program for all slot
+        caches. Raises `MigrationAborted` with the engine still serving at
+        its old TP."""
         if tp not in self.meshes:
             raise ValueError(
                 f"switch_tp({tp}): no mesh for TP {tp}; this engine has TPs {self.tps}"
@@ -268,54 +319,107 @@ class ServingEngine:
             )
         with _span("engine.insert"):
             self.slots.arrays = self._insert_fn(self.slots.arrays, seq_cache, slot)
+        self._drain()  # while the prefill runs: the host's tokens are whole after it
         with _span("engine.first_token"):
             tok = int(np.asarray(nxt)[0])
         req.slot = slot
-        req.state = RequestState.DECODE
         req.generated.append(tok)
         req.first_token_s = time.perf_counter()
+        if self.econf.record_logits:
+            self.logit_trace.setdefault(req.req_id, []).append(np.asarray(logits)[0])
+        if req.done:  # one token asked for: no decode step
+            self._finish(req, req.first_token_s)
+            self.slots.release(slot)
+            return True
+        req.state = RequestState.DECODE
         self.slot_req[slot] = req
         self.slots.lengths[slot] = req.prompt_len
         self.next_tokens[slot] = tok
-        if self.econf.record_logits:
-            self.logit_trace.setdefault(req.req_id, []).append(np.asarray(logits)[0])
+        self._tokens = None
         return True
 
+    @property
+    def busy(self) -> bool:
+        """Whether `step` has work: a slot to decode, a step to read, or a
+        finished request to return."""
+        return (self._inflight is not None or bool(self._finished)
+                or any(r is not None for r in self.slot_req))
+
     def step(self) -> List[Request]:
-        """One decode iteration over all active slots; returns finished."""
+        """Dispatch the next decode iteration over all active slots, then
+        read the previous one; returns the requests whose last token that
+        read brought. With nothing to dispatch, only reads."""
         with jax.profiler.StepTraceAnnotation("engine.step", step_num=self.stats.steps):
             return self._step()
 
     def _step(self) -> List[Request]:
-        tokens = self.next_tokens.reshape(-1, 1)
+        if any(r is not None for r in self.slot_req):
+            prev, self._inflight = self._inflight, None
+            self._dispatch()
+            if prev is not None:
+                self.stats.overlapped += 1
+                self._read(prev)
+        else:
+            self._drain()
+        finished, self._finished = self._finished, []
+        return finished
+
+    def _dispatch(self) -> None:
+        """Dispatch one decode step from the device tokens; advance the
+        positions and release the slot of each request this step ends."""
         positions = self.slots.lengths.astype(np.int32)
         with _span("engine.decode"):
             nxt, logits, self.slots.arrays = self._decode_fns[self.tp](
-                self.storage, self.slots.arrays, tokens, positions
+                self.storage, self.slots.arrays, self._device_tokens(), positions
             )
-        with _span("engine.sync"):
-            nxt = np.asarray(nxt)
-        with _span("engine.logits"):
-            logits = np.asarray(logits)
+        self._tokens = nxt
         self.stats.steps += 1
-        finished = []
+        rows = []
+        for slot, req in enumerate(self.slot_req):
+            if req is None:
+                continue
+            self.slots.lengths[slot] += 1
+            length = int(self.slots.lengths[slot])
+            # tokens once this step is read: the prefill's one, one per step
+            last = (length - req.prompt_len + 1 >= req.max_new_tokens
+                    or length + 1 >= self.econf.max_len)
+            rows.append((slot, req, last))
+            if last:
+                self.slot_req[slot] = None
+                self.slots.release(slot)
+        self._inflight = (nxt, logits if self.econf.record_logits else None, rows)
+
+    def _read(self, inflight: tuple) -> None:
+        """Bring a dispatched step's tokens (and logits, when recording) to
+        the host and append them to their requests."""
+        nxt, logits, rows = inflight
+        with _span("engine.sync"):
+            nxt = np.asarray(nxt)[:, 0]
+        with _span("engine.logits"):  # opened on every read, as SPANS says
+            logits = None if logits is None else np.asarray(logits)
         with _span("engine.slots"):
-            for slot, req in enumerate(self.slot_req):
-                if req is None:
-                    continue
-                self.slots.lengths[slot] += 1
-                tok = int(nxt[slot])
-                req.generated.append(tok)
-                self.next_tokens[slot] = tok
-                if self.econf.record_logits:
+            self.next_tokens[:] = nxt
+            now = time.perf_counter()
+            for slot, req, last in rows:
+                req.generated.append(int(nxt[slot]))
+                if logits is not None:
                     self.logit_trace[req.req_id].append(logits[slot])
-                if req.done or self.slots.lengths[slot] + 1 >= self.econf.max_len:
-                    req.state = RequestState.DONE
-                    req.finish_s = time.perf_counter()
-                    finished.append(req)
-                    self.slot_req[slot] = None
-                    self.slots.release(slot)
-        return finished
+                if last:
+                    self._finish(req, now)
+
+    def _drain(self) -> None:
+        """Read the step in flight, where one is, before the next dispatch."""
+        if self._inflight is None:
+            return
+        inflight, self._inflight = self._inflight, None
+        with _span("engine.drain"):
+            self._read(inflight)
+        self.stats.drains += 1
+
+    def _finish(self, req: Request, now: float) -> None:
+        req.state = RequestState.DONE
+        req.finish_s = now
+        self._finished.append(req)
 
     def run(
         self,
@@ -329,7 +433,7 @@ class ServingEngine:
         pending = list(requests)
         done: List[Request] = []
         step_no = 0
-        while (pending or any(r is not None for r in self.slot_req)) and step_no < max_steps:
+        while (pending or self.busy) and step_no < max_steps:
             if step_no in switch_schedule:
                 self.switch_tp(switch_schedule[step_no])
             while pending and self.slots.free:

@@ -16,6 +16,8 @@ Checks:
       reload on a shrunken pool (WeightStore.shrink) still serves correct
       logits.
   engine       — greedy trajectories are unchanged by mid-stream TP switches.
+  engine_pipeline — the one-step-ahead decode loop compiles nothing while
+      serving across TP switches, and drains before each switch.
   engine_rollback — a TP switch whose migration fails leaves the engine at
       its old TP, serving the same tokens, and a retry succeeds.
 """
@@ -340,6 +342,38 @@ def check_engine() -> None:
     )
 
 
+def check_engine_pipeline() -> None:
+    """The decode loop one step ahead of the host, across TP switches: an
+    uploaded token vector and a decode step's own output tokens go to one
+    executable per TP (data-sharded token vectors included), which warm-up
+    compiles, so nothing compiles while serving; each scheduled switch
+    reads the step in flight first; every request gets exactly its
+    max_new_tokens."""
+    import jax.monitoring
+
+    mk_engine, mk_requests = _tiny_engine()
+    eng = mk_engine()
+    compiles = {"warmup": [], "serving": []}
+    phase = ["warmup"]
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, duration, fun_name="", **kw: compiles[phase[0]].append(fun_name)
+        if event == "/jax/core/compile/backend_compile_duration" else None
+    )
+    eng.warmup()
+    decode = [f for f in compiles["warmup"] if f == "jit(decode_step)"]
+    assert len(decode) == len(eng.tps), f"{len(decode)} decode programs for TPs {eng.tps}"
+    phase[0] = "serving"
+    done = eng.run(mk_requests(), switch_schedule={3: 2, 7: 4, 13: 1, 19: 2})
+    assert not compiles["serving"], f"compiled while serving: {compiles['serving']}"
+    log = eng.stats.switch_log
+    assert [(s["from_tp"], s["to_tp"]) for s in log] == [(1, 2), (2, 4), (4, 1), (1, 2)], log
+    assert sorted(len(r.generated) for r in done) == [r.max_new_tokens for r in mk_requests()]
+    assert eng.stats.drains >= len(log) and eng.stats.overlapped > 0, eng.stats
+    print(f"engine_pipeline: {eng.stats.steps} steps, {eng.stats.overlapped} overlapped, "
+          f"{eng.stats.drains} drains across {len(log)} TP switches; one decode program "
+          "a TP, nothing compiled while serving")
+
+
 def check_engine_rollback() -> None:
     """A TP switch whose cache migration fails raises MigrationAborted and
     leaves the engine as it was: the same TP, storage, caches and switch
@@ -360,7 +394,7 @@ def check_engine_rollback() -> None:
         raise RuntimeError("device lost mid-migration")
 
     pending, done, step = mk_requests(), [], 0
-    while pending or any(r is not None for r in eng.slot_req):
+    while pending or eng.busy:
         if step == 3:
             before = (eng.tp, eng.storage, eng.slots.arrays, list(eng.stats.switch_log))
             engine_mod.migrate_cache = dying_migrate
@@ -396,6 +430,7 @@ CHECKS = {
     "migration": check_migration,
     "fault_abort": check_fault_abort,
     "engine": check_engine,
+    "engine_pipeline": check_engine_pipeline,
     "engine_rollback": check_engine_rollback,
 }
 

@@ -51,6 +51,11 @@ def test_engine_serves_with_tp_switches():
     assert "switch" in out
 
 
+def test_engine_pipeline_compiles_nothing_while_serving_across_switches():
+    out = _run("engine_pipeline")
+    assert "nothing compiled while serving" in out
+
+
 def test_engine_switch_rolls_back_when_migration_fails():
     out = _run("engine_rollback")
     assert "aborted switch TP 1->2 rolled back" in out
